@@ -11,6 +11,8 @@ from __future__ import annotations
 
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (bench_cdn, bench_contention, bench_costfoo, bench_crossover,
                bench_exact, bench_fleet, bench_flow_scale, bench_governor,
                bench_heterogeneity, bench_kernels, bench_policy_throughput,
@@ -32,6 +34,7 @@ ALL = {
 
 
 def main() -> None:
+    enable_compile_cache()
     names = sys.argv[1:] or list(ALL)
     unknown = [n for n in names if n not in ALL]
     if unknown:

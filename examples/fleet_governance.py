@@ -23,6 +23,7 @@ import math
 from repro.egress.cache import EgressCache, ONLINE_POLICIES
 from repro.fleet import Fleet, SimNetwork, hash_partition
 from repro.online.scenario import regime_shift_scenario
+from repro.launch.compile_cache import enable_compile_cache
 
 N = 4
 SCENARIO = dict(n_phase=3000, seed=0, n_big_active=12, big_bytes=1 << 18)
@@ -40,6 +41,7 @@ def run_fixed(sc, policy):
 
 
 def main():
+    enable_compile_cache()
     sc = regime_shift_scenario(**SCENARIO)
     print(f"trace: {sc.num_requests} requests over {N} hosts, "
           f"price flips {sc.price_a.name} -> {sc.price_b.name} "

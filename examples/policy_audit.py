@@ -14,11 +14,13 @@ from repro.core import (PRICE_VECTORS, exact_opt_uniform, heterogeneity,
                         miss_costs, twemcache_like)
 from repro.core.policies_jax import sweep_jax
 from repro.online import MetricsRegistry
+from repro.launch.compile_cache import enable_compile_cache
 
 OUT = pathlib.Path(__file__).resolve().parent.parent / "benchmarks" / "out"
 
 
 def main():
+    enable_compile_cache()
     metrics = MetricsRegistry()
     tr = twemcache_like(n_requests=8000, seed=1)
     # page-cache view: audit the *cost* structure with uniform pages

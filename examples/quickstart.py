@@ -12,9 +12,11 @@ import numpy as np
 from repro.core import (PRICE_VECTORS, Trace, exact_opt_uniform,
                         heterogeneity, miss_costs, regret, simulate,
                         zipf_trace)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     print("=== cloud-egress caching quickstart ===\n")
     # a page-cache workload: uniform 4 KiB pages, heterogeneous miss costs
     # (same-region vs cross-region objects — cost varies, size doesn't)

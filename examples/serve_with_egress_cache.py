@@ -11,9 +11,11 @@ import numpy as np
 from repro.configs import get_config
 from repro.models.registry import get_model
 from repro.serve.engine import Request, ServeEngine
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     cfg = get_config("gemma3-4b", smoke=True)
     model = get_model(cfg)
     params = model.init(jax.random.key(0))

@@ -30,6 +30,7 @@ from repro.configs import get_config
 from repro.models.registry import get_model
 from repro.obs import EventLog, MetricsRegistry, Tracer
 from repro.serve.engine import Request, ServeEngine
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def span_tree(tracer: Tracer, root) -> list[str]:
@@ -56,6 +57,7 @@ def span_tree(tracer: Tracer, root) -> list[str]:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None,
                     help="directory for obs.json / trace.chrome.json / "

@@ -26,9 +26,11 @@ from repro.train.data import DataPipeline, ShardedTokenDataset
 from repro.train.driver import DriverConfig, TrainDriver
 from repro.train.optim import OptimizerConfig, make_optimizer
 from repro.train.trainer import make_train_step
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--smoke", action="store_true")

@@ -271,12 +271,12 @@ def _round_arrays(pt: np.ndarray, pu: np.ndarray, psave: np.ndarray,
     the exact same float expression shapes) and identical feasibility
     predicate, re-expressed as headroom range-mins — so accepted sets and
     the saved-dollar sum match the oracle bit for bit when the occupancy
-    arithmetic is exact (integer-valued sizes). Returns (saved, accepted
-    interval indices).
+    arithmetic is exact (integer-valued sizes). Returns (saved, int64 array
+    of accepted interval indices).
     """
     m = len(pt)
     if m == 0:
-        return 0.0, []
+        return 0.0, np.zeros(0, np.int64)
     # reference key: (-(x > 0.999), -x * save / max(size, 1)); lexsort is
     # stable ascending with the LAST key primary, matching sorted()
     dens = (-x) * psave / np.maximum(psize, 1.0)
@@ -336,7 +336,7 @@ def _round_arrays(pt: np.ndarray, pu: np.ndarray, psave: np.ndarray,
                 probe_gap = 1 if cache_hit else min(probe_gap * 2, 256)
                 cache_hit = False
                 since_probe = 0
-    return saved, accepted
+    return saved, np.asarray(accepted, np.int64)
 
 
 def round_fractional(ids: np.ndarray, sizes: np.ndarray, B: float,
@@ -461,8 +461,10 @@ def cost_foo(trace: Trace, costs: np.ndarray, B: float,
                                            _round_tol(B))
     profile["round_seconds"] = time.perf_counter() - t_round
     profile["rounded_intervals"] = len(accepted)
-    if validate and accepted:
-        _validate_schedule(pt, pu, pz, accepted, zcap, T, B, use_pallas)
+    if validate and len(accepted):
+        excess, tol = _validate_schedule(pt, pu, pz, accepted, zcap, T, B,
+                                         use_pallas)
+        profile.update(validate_excess=excess, validate_tol=tol)
 
     upper = total - (rounded_save + free_save)
     for p in policies:
@@ -478,21 +480,23 @@ def _validate_schedule(pt, pu, pz, accepted, zcap, T, B, use_pallas):
 
     The kernel scans in float32, so the tolerance is the float32 precision
     of a B-sized running sum, not the rounding pass's own 1e-9·B.
+    Returns (worst excess of occupancy over zcap, tolerance).
     """
     import jax.numpy as jnp
 
     from repro.kernels import ops as kops
 
-    acc = np.asarray(accepted, np.int64)
-    deltas = interval_deltas(pt[acc], pu[acc], pz[acc], T)
+    deltas = interval_deltas(pt[accepted], pu[accepted], pz[accepted], T)
     _, excess = kops.occupancy_feasible(jnp.asarray(deltas, jnp.float32),
                                         jnp.asarray(zcap, jnp.float32),
                                         use_pallas=use_pallas)
+    excess = float(excess)
     tol = max(_round_tol(B), 1e-4 * max(1.0, B))
-    if float(excess) > tol:
+    if excess > tol:
         raise AssertionError(
-            f"rounded schedule exceeds zcap by {float(excess):.6g} "
+            f"rounded schedule exceeds zcap by {excess:.6g} "
             f"(tolerance {tol:.6g})")
+    return excess, tol
 
 
 def _free_intervals(trace: Trace, costs: np.ndarray, B: float) -> list[Interval]:

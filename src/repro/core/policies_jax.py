@@ -203,6 +203,7 @@ def simulate_jax(policy: str, ids: np.ndarray, costs: np.ndarray,
     return float(d), int(h)
 
 
+@functools.partial(jax.jit, static_argnames=("num_objects", "use_pallas"))
 def _sweep_grid(weight_stack, ids, nxt, cost_matrix, sizes, budgets,
                 num_objects: int, use_pallas: bool):
     """(Q policies x P prices x K budgets) grid as one compiled program."""
@@ -218,16 +219,6 @@ def _sweep_grid(weight_stack, ids, nxt, cost_matrix, sizes, budgets,
             in_axes=(None, 0, None)),
         in_axes=(0, None, None))
     return f(weight_stack, cost_matrix, budgets)
-
-
-@functools.cache
-def _sweep_grid_jit(donate: bool):
-    """Jit the grid once per donation mode. The stacked weights and the
-    price matrix are consumed by the sweep (freshly staged per call), so on
-    accelerators their buffers are donated; CPU jit would only warn."""
-    return jax.jit(_sweep_grid,
-                   static_argnames=("num_objects", "use_pallas"),
-                   donate_argnums=(0, 3) if donate else ())
 
 
 def sweep_jax(policy, ids: np.ndarray, cost_matrix: np.ndarray,
@@ -246,7 +237,10 @@ def sweep_jax(policy, ids: np.ndarray, cost_matrix: np.ndarray,
     profile:     pass a dict to get compile time separated from execute
                  time (DESIGN.md §9): filled with `compile_s` (trace +
                  lower + XLA compile, ~0 when the executable is already
-                 cached) and `execute_s` (device run, block_until_ready).
+                 cached), `execute_s` (device run, block_until_ready) and
+                 `mosaic_kernels`, the compiled program's count of
+                 Mosaic kernel calls (0 for the jnp path or the Pallas
+                 interpreter).
     """
     single = isinstance(policy, str)
     if single:
@@ -261,20 +255,21 @@ def sweep_jax(policy, ids: np.ndarray, cost_matrix: np.ndarray,
     n = int(num_objects if num_objects is not None else ids.max() + 1)
     nxt = jnp.asarray(next_use_indices(ids).astype(np.int32))
     s = jnp.ones(n, jnp.float32) if sizes is None else jnp.asarray(sizes, jnp.float32)
-    fn = _sweep_grid_jit(jax.default_backend() != "cpu")
     args = (jnp.asarray(stack), jnp.asarray(ids), nxt,
             jnp.asarray(cost_matrix, dtype=jnp.float32), s,
             jnp.asarray(budgets, dtype=jnp.int32))
     up = _resolve_use_pallas(use_pallas)
     if profile is None:
-        out = fn(*args, n, up)
+        out = _sweep_grid(*args, n, up)
     else:
         t0 = time.perf_counter()
-        compiled = fn.lower(*args, n, up).compile()
+        compiled = _sweep_grid.lower(*args, n, up).compile()
         t1 = time.perf_counter()
         out = jax.block_until_ready(compiled(*args))
         t2 = time.perf_counter()
         profile.update(compile_s=t1 - t0, execute_s=t2 - t1,
-                       cells=int(out.size))
+                       cells=int(out.size),
+                       mosaic_kernels=compiled.as_text().count(
+                           "tpu_custom_call"))
     out = np.asarray(out)
     return out[0] if single else out

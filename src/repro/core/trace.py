@@ -138,10 +138,8 @@ def wiki_cdn_like(n_objects: int = 6000, n_requests: int = 20000,
 def next_use_indices(ids: np.ndarray, n_objects: int | None = None) -> np.ndarray:
     """next(t): index of the next request of the same object, or T if none.
 
-    Reference (numpy) implementation; the Pallas kernel `kernels/next_use`
-    mirrors it and is verified against this in tests. Vectorized: a stable
-    sort groups each object's accesses in time order, so the successor
-    within a group IS the next use.
+    Vectorized: a stable sort groups each object's accesses in time order,
+    so the successor within a group IS the next use.
     """
     ids = np.asarray(ids)
     T = ids.shape[0]

@@ -2,11 +2,12 @@
 
 Every priority policy's inner loop (LRU/LFU/GDS/GDSF/Belady/cost-Belady,
 paper §2) is "find the cached object with the smallest (score, last_touch)".
-A heap does not vectorize; on TPU the whole object table lives in VMEM and
-the reduction runs at vector width (DESIGN.md §3). This kernel blocks the
-table (BLOCK_N multiple of 128 lanes), keeps a running lexicographic
-minimum in SMEM scratch across sequential grid steps, and emits the final
-(victim index, victim score).
+A heap does not vectorize; on TPU the whole object table streams through
+VMEM and the reduction runs at vector width (DESIGN.md §3). The table is
+laid out as (rows, 128) tiles; each sequential grid step reduces one block
+of rows to its lexicographic (score, touch, index) minimum with three
+masked `min` reductions over an index iota, and folds it into a running
+minimum kept in the resident output blocks.
 """
 from __future__ import annotations
 
@@ -17,78 +18,69 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .layout import LANES, to_tiles
+
 __all__ = ["evict_argmin_pallas"]
 
 _BIG = 3.4e38
 _INT_BIG = 2**31 - 1
 
-# jax >= 0.5 renamed pltpu.TPUMemorySpace -> pltpu.MemorySpace; the SMEM
-# constant exists under both spellings.
-_SMEM = getattr(pltpu, "MemorySpace", getattr(pltpu, "TPUMemorySpace", None)).SMEM
+
+def _min11(x):
+    """Minimum of a 2-D tile as a (1, 1) tile (no scalar extraction)."""
+    return jnp.min(jnp.min(x, axis=1, keepdims=True), axis=0, keepdims=True)
 
 
-def _kernel(scores_ref, touch_ref, mask_ref, idx_out, val_out,
-            best_ref, *, block_n: int, num_blocks: int):
+def _kernel(scores_ref, touch_ref, mask_ref, idx_out, val_out, touch_best,
+            *, block_rows: int):
     g = pl.program_id(0)
 
     @pl.when(g == 0)
     def _init():
-        best_ref[0] = jnp.float32(_BIG)   # best score
-        best_ref[1] = jnp.float32(_INT_BIG)  # best touch (lex tiebreak)
-        best_ref[2] = jnp.float32(-1)     # best index
+        val_out[...] = jnp.full(val_out.shape, _BIG, jnp.float32)
+        touch_best[...] = jnp.full(touch_best.shape, _INT_BIG, jnp.int32)
+        idx_out[...] = jnp.full(idx_out.shape, _INT_BIG, jnp.int32)
 
-    s = jnp.where(mask_ref[...], scores_ref[...].astype(jnp.float32),
-                  jnp.float32(_BIG))
-    local_min = jnp.min(s)
-    tie = s <= local_min
-    touch = jnp.where(tie, touch_ref[...], _INT_BIG)
-    local_arg = jnp.argmin(touch)
-    local_touch = touch[local_arg].astype(jnp.float32)
-    local_idx = (g * block_n + local_arg).astype(jnp.float32)
+    s = jnp.where(mask_ref[...] != 0, scores_ref[...], jnp.float32(_BIG))
+    shape = s.shape
+    idx = (g * (block_rows * LANES)
+           + jax.lax.broadcasted_iota(jnp.int32, shape, 0) * LANES
+           + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    m = _min11(s)
+    tie = s <= m
+    t = jnp.where(tie, touch_ref[...], _INT_BIG)
+    mt = _min11(t)
+    mi = _min11(jnp.where(tie & (t == mt), idx, _INT_BIG))
 
-    better = (local_min < best_ref[0]) | (
-        (local_min == best_ref[0]) & (local_touch < best_ref[1]))
-
-    @pl.when(better)
-    def _upd():
-        best_ref[0] = local_min
-        best_ref[1] = local_touch
-        best_ref[2] = local_idx
-
-    @pl.when(g == num_blocks - 1)
-    def _emit():
-        safe = jnp.maximum(best_ref[2], 0.0)
-        idx_out[0] = safe.astype(jnp.int32)
-        val_out[0] = best_ref[0]
+    bs, bt, bi = val_out[...], touch_best[...], idx_out[...]
+    better = (m < bs) | ((m == bs) & ((mt < bt) | ((mt == bt) & (mi < bi))))
+    val_out[...] = jnp.where(better, m, bs)
+    touch_best[...] = jnp.where(better, mt, bt)
+    idx_out[...] = jnp.where(better, mi, bi)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def evict_argmin_pallas(scores: jax.Array, touch: jax.Array, mask: jax.Array,
-                        block_n: int = 2048, interpret: bool = True):
-    """Lexicographic argmin of (score, touch) over mask==True entries.
+                        block_n: int = 32768, interpret: bool = False):
+    """Lexicographic argmin of (score, touch, index) over mask==True entries.
 
     scores: (N,) float; touch: (N,) int32; mask: (N,) bool.
     Returns (victim_index int32 scalar, victim_score float32 scalar);
     score is +BIG when the mask is empty.
     """
-    n = scores.shape[0]
-    num_blocks = -(-n // block_n)
-    n_pad = num_blocks * block_n
-    if n_pad != n:
-        scores = jnp.pad(scores, (0, n_pad - n))
-        touch = jnp.pad(touch, (0, n_pad - n))
-        mask = jnp.pad(mask, (0, n_pad - n))
+    s, block_rows = to_tiles(scores.astype(jnp.float32), block_n, 0.0)
+    t, _ = to_tiles(touch.astype(jnp.int32), block_n, _INT_BIG)
+    k, _ = to_tiles(mask.astype(jnp.int32), block_n, 0)
+    block = pl.BlockSpec((block_rows, LANES), lambda g: (g, 0))
+    carry = pl.BlockSpec((1, LANES), lambda g: (0, 0))
     idx, val = pl.pallas_call(
-        functools.partial(_kernel, block_n=block_n, num_blocks=num_blocks),
-        grid=(num_blocks,),
-        in_specs=[pl.BlockSpec((block_n,), lambda g: (g,)),
-                  pl.BlockSpec((block_n,), lambda g: (g,)),
-                  pl.BlockSpec((block_n,), lambda g: (g,))],
-        out_specs=[pl.BlockSpec(memory_space=_SMEM),
-                   pl.BlockSpec(memory_space=_SMEM)],
-        out_shape=[jax.ShapeDtypeStruct((1,), jnp.int32),
-                   jax.ShapeDtypeStruct((1,), jnp.float32)],
-        scratch_shapes=[pltpu.SMEM((3,), jnp.float32)],
+        functools.partial(_kernel, block_rows=block_rows),
+        grid=(s.shape[0] // block_rows,),
+        in_specs=[block, block, block],
+        out_specs=[carry, carry],
+        out_shape=[jax.ShapeDtypeStruct((1, LANES), jnp.int32),
+                   jax.ShapeDtypeStruct((1, LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, LANES), jnp.int32)],
         interpret=interpret,
-    )(scores, touch.astype(jnp.int32), mask)
-    return idx[0], val[0]
+    )(s, t, k)
+    return idx[0, 0], val[0, 0]

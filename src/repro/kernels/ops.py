@@ -1,43 +1,36 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On this CPU container the kernels run with interpret=True (the Pallas
-interpreter executes the kernel body in Python); on a real TPU pass
-interpret=False (or rely on the default backend detection below) to lower
-to Mosaic. The pure-jnp oracles in ref.py define the semantics either way.
+On a TPU backend the kernels are compiled by Mosaic; a kernel that the
+compiler refuses is an error, never a silent hand-off to the oracle. On
+any other backend they run in the Pallas interpreter, which executes the
+same kernel body and is what the CPU tests exercise. The pure-jnp oracles
+in ref.py define the semantics either way.
 """
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from . import ref
 from .evict_argmin import evict_argmin_pallas
 from .interval_occupancy import (interval_occupancy_pallas,
                                  occupancy_feasible_pallas)
-from .next_use import next_use_pallas
 
-__all__ = ["next_use", "evict_argmin", "interval_occupancy",
-           "occupancy_feasible", "on_tpu"]
+__all__ = ["evict_argmin", "interval_occupancy", "occupancy_feasible",
+           "on_tpu"]
 
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def next_use(ids: jax.Array, num_objects: int, *, block_t: int = 1024,
-             use_pallas: bool | None = None) -> jax.Array:
-    """next(t) per request (T where the object never recurs)."""
-    if use_pallas is None:
-        use_pallas = True
-    if use_pallas:
-        return next_use_pallas(ids, num_objects, block_t=block_t,
-                               interpret=not on_tpu())
-    return ref.next_use_ref(ids, num_objects)
-
-
 def evict_argmin(scores: jax.Array, touch: jax.Array, mask: jax.Array, *,
-                 block_n: int = 2048, use_pallas: bool | None = None):
-    """Victim selection: lexicographic argmin of (score, touch) where mask."""
+                 block_n: int = 32768, use_pallas: bool | None = None):
+    """Victim selection: lexicographic argmin of (score, touch) where mask.
+
+    The default block (256 rows of 128) was the fastest of 2048..131072
+    elements for the 96-cell grid's batched call over 2^20 objects on a
+    TPU v5e (PERF.md); smaller tables run as one block.
+    """
     if use_pallas is None:
         use_pallas = True
     if use_pallas:
@@ -63,7 +56,7 @@ def occupancy_feasible(deltas: jax.Array, zcap: jax.Array, *,
 
     The device-resident check of cost-FOO's rounded schedule
     (DESIGN.md §4): deltas are the accepted intervals' range-adds, the
-    fused scan carries occupancy + running max(occ - zcap) in SMEM.
+    fused scan carries occupancy + running max(occ - zcap) in VMEM.
     """
     if use_pallas is None:
         use_pallas = True

@@ -8,26 +8,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["next_use_ref", "evict_argmin_ref", "interval_occupancy_ref",
+__all__ = ["evict_argmin_ref", "interval_occupancy_ref",
            "occupancy_feasible_ref"]
-
-
-def next_use_ref(ids: jax.Array, num_objects: int) -> jax.Array:
-    """next(t): index of the next request of ids[t], or T if none.
-
-    Reverse scan carrying a last-seen table — the jnp analogue of the
-    Pallas kernel's VMEM-resident table.
-    """
-    T = ids.shape[0]
-    init = jnp.full((num_objects,), T, dtype=jnp.int32)
-
-    def step(last_seen, t):
-        i = ids[t]
-        nxt = last_seen[i]
-        return last_seen.at[i].set(t), nxt
-
-    _, out = jax.lax.scan(step, init, jnp.arange(T - 1, -1, -1, dtype=jnp.int32))
-    return out[::-1]
 
 
 def evict_argmin_ref(scores: jax.Array, touch: jax.Array,

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, SHAPES, cells, get_config
-from repro.launch.hlo_analysis import analyze_collectives, cost_analysis_dict
+from repro.launch.hlo_analysis import analyze_collectives
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import count_params, model_flops, terms_from_analysis
 from repro.models.registry import get_model
@@ -240,7 +240,7 @@ def run_cell(arch: str, shape_id: str, *, multi_pod: bool, moe_ep=False,
         extra_flops = extra_bytes = 0.0
         rec["probes"] = {}
         for pname, (plow, mult) in probes.items():
-            pc = cost_analysis_dict(plow.compile())
+            pc = plow.compile().cost_analysis()
             pf = float(pc.get("flops", 0.0))
             pb = float(pc.get("bytes accessed", 0.0))
             extra_flops += pf * mult
@@ -257,7 +257,7 @@ def run_cell(arch: str, shape_id: str, *, multi_pod: bool, moe_ep=False,
         peak_gib=(ma.argument_size_in_bytes + ma.output_size_in_bytes
                   + ma.temp_size_in_bytes - ma.alias_size_in_bytes) / 2**30,
     )
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     pm = rec.get("program_multiplier", 1)
     flops = float(ca.get("flops", 0.0)) * pm + extra_flops
     byts = float(ca.get("bytes accessed", 0.0)) * pm + extra_bytes

@@ -18,16 +18,8 @@ import dataclasses
 import re
 from collections import defaultdict
 
-__all__ = ["CollectiveStats", "analyze_collectives", "cost_analysis_dict"]
+__all__ = ["CollectiveStats", "analyze_collectives"]
 
-
-def cost_analysis_dict(compiled) -> dict:
-    """compiled.cost_analysis() as a flat dict across jax versions (older
-    releases return a one-element list of dicts, one per partition)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

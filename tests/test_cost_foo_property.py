@@ -76,7 +76,7 @@ def test_rounded_schedule_never_exceeds_zcap(data):
     zcap = zcap_profile(ids, sizes, B)
     tol = _round_tol(B)
     _, accepted = _round_arrays(t, u, save, size, x, zcap, tol)
-    if not accepted.any():
+    if not len(accepted):
         return
     deltas = interval_deltas(t[accepted], u[accepted], size[accepted], T)
     occ = np.cumsum(deltas)

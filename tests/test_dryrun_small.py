@@ -16,7 +16,7 @@ _SCRIPT = textwrap.dedent("""
     from repro.parallel.sharding import make_rules, params_sharding, batch_spec
     from repro.train.optim import OptimizerConfig, make_optimizer
     from repro.train.trainer import make_train_step, train_state_shardings
-    from repro.launch.hlo_analysis import analyze_collectives, cost_analysis_dict
+    from repro.launch.hlo_analysis import analyze_collectives
     from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((2, 4), ("data", "model"))
@@ -34,7 +34,7 @@ _SCRIPT = textwrap.dedent("""
                           out_shardings=(NamedSharding(mesh, P()), ps, osd),
                           donate_argnums=(0, 1)).lower(ap, aos, batch)
         compiled = lowered.compile()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     cs = analyze_collectives(compiled.as_text())
     ma = compiled.memory_analysis()
     print(json.dumps({

@@ -8,19 +8,6 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.core.trace import next_use_indices
-
-
-@pytest.mark.parametrize("T,N,block_t", [
-    (64, 8, 16), (100, 5, 32), (1000, 37, 256), (4096, 513, 1024),
-    (777, 13, 128), (1, 1, 8), (2048, 2048, 512),
-])
-def test_next_use_shapes(T, N, block_t):
-    rng = np.random.default_rng(T * 31 + N)
-    ids = rng.integers(0, N, T).astype(np.int32)
-    got = np.asarray(ops.next_use(jnp.asarray(ids), N, block_t=block_t))
-    want = next_use_indices(ids, N)
-    np.testing.assert_array_equal(got, want)
 
 
 @pytest.mark.parametrize("N,block_n,dtype", [

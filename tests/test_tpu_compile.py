@@ -1,0 +1,78 @@
+"""Compile guards: the main-path Pallas kernels and the replay grid, compiled
+by the TPU compiler for a described (not attached) v5e chip.
+
+Interpret-mode tests cannot see what Mosaic refuses (unsupported reductions,
+scalar stores to VMEM, unaligned blocks). These compile each kernel at
+deployment sizes and the 96-cell grid with the real kernel inside it. The
+topology is described inside a fixture, never at import, so every test
+worker collects the same tests and only the one that runs this file loads
+the TPU compiler.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import policies_jax
+from repro.kernels import ops
+from repro.kernels.evict_argmin import evict_argmin_pallas
+from repro.kernels.interval_occupancy import (interval_occupancy_pallas,
+                                              occupancy_feasible_pallas)
+
+
+@pytest.fixture(scope="module")
+def chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler installed here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+
+def _assert_kernel(lowered):
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n", [65_536, 1_048_576])
+def test_evict_argmin_compiles(chip, n):
+    _assert_kernel(evict_argmin_pallas.lower(
+        _spec(chip, (n,), jnp.float32), _spec(chip, (n,), jnp.int32),
+        _spec(chip, (n,), jnp.bool_), interpret=False))
+
+
+def test_occupancy_feasible_compiles(chip):
+    t = 262_144
+    _assert_kernel(occupancy_feasible_pallas.lower(
+        _spec(chip, (t,), jnp.float32), _spec(chip, (t,), jnp.float32),
+        interpret=False))
+
+
+def test_interval_occupancy_compiles(chip):
+    _assert_kernel(interval_occupancy_pallas.lower(
+        _spec(chip, (262_144,), jnp.float32), interpret=False))
+
+
+def test_replay_grid_compiles_with_kernel(chip, monkeypatch):
+    """The 6 policies x 4 prices x 4 budgets program, with the default
+    kernel choice resolved as on a TPU backend."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    use_pallas = policies_jax._resolve_use_pallas(None)
+    assert use_pallas
+    n, t = 65_536, 8_192
+    args = (_spec(chip, (6, 6), jnp.float32), _spec(chip, (t,), jnp.int32),
+            _spec(chip, (t,), jnp.int32), _spec(chip, (4, n), jnp.float32),
+            _spec(chip, (n,), jnp.float32), _spec(chip, (4,), jnp.int32))
+    lowered = policies_jax._sweep_grid.lower(*args, n, use_pallas)
+    assert np.prod(lowered.out_info.shape) == 96
+    _assert_kernel(lowered)
