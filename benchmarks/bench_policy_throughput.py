@@ -5,8 +5,8 @@ Two sweep shapes: the original (price x budget) batch for one policy, and
 the full (6 policies x 4 prices x 4 budgets) panel as ONE compiled program
 (stacked `PolicyWeights` as a third vmap axis).
 
-Obs additions (DESIGN.md §9): `sweep_jax(profile=...)` separates compile
-time from execute time (cold vs warm), and tracing overhead is measured
+Obs additions (DESIGN.md §9): `sweep_jax`'s spans separate compile time
+from execute time (cold vs warm), and tracing overhead is measured
 at two granularities. The acceptance gate is the governed `ServeEngine`
 loop (the acceptance criterion's workload): span tracer + decision event
 log enabled must cost < 10% over the untraced engine, and a falsy (no-op)
@@ -190,21 +190,29 @@ def main():
          f"req_per_s={T/dt_jax:.0f};speedup_vs_py={dt_py/dt_jax:.2f}x")
 
     # batched 4 price vectors x 4 budgets in one device program, with the
-    # compile/execute split (cold then warm — warm compile hits the cache)
+    # compile/execute split (cold then warm — warm reuses the executable)
     cost_matrix = np.stack([costs * (10 ** k) for k in range(4)])
     budgets = np.array([16, 32, 64, 128])
-    cold, warm = {}, {}
-    sweep_jax("gdsf", ids, cost_matrix, budgets, num_objects=N, profile=cold)
+    cold, warm = Tracer(), Tracer()
+    sweep_jax("gdsf", ids, cost_matrix, budgets, num_objects=N, tracer=cold)
     out = sweep_jax("gdsf", ids, cost_matrix, budgets, num_objects=N,
-                    profile=warm)
+                    tracer=warm)
+
+    def seconds(tracer, *names):
+        return sum(sp.dur for sp in tracer.spans() if sp.name in names)
+
+    compile_s = seconds(cold, "replay.lower", "replay.compile")
+    execute_s = seconds(cold, "replay.execute")
+    warm_execute_s = seconds(warm, "replay.execute")
     cells = out.size
-    emit("policy_jax_sweep_16cells", warm["execute_s"],
-         f"cell_per_s={cells/warm['execute_s']:.2f};"
-         f"req_per_s={cells*T/warm['execute_s']:.0f}")
-    emit("policy_jax_sweep_profile", cold["compile_s"] + cold["execute_s"],
-         f"compile_s={cold['compile_s']:.3f};execute_s={cold['execute_s']:.4f};"
-         f"warm_compile_s={warm['compile_s']:.4f};"
-         f"compile_frac={cold['compile_s']/(cold['compile_s']+cold['execute_s']):.3f}")
+    emit("policy_jax_sweep_16cells", warm_execute_s,
+         f"cell_per_s={cells/warm_execute_s:.2f};"
+         f"req_per_s={cells*T/warm_execute_s:.0f}")
+    emit("policy_jax_sweep_profile", compile_s + execute_s,
+         f"compile_s={compile_s:.3f};execute_s={execute_s:.4f};"
+         f"warm_compile_s="
+         f"{seconds(warm, 'replay.lower', 'replay.compile'):.4f};"
+         f"compile_frac={compile_s/(compile_s+execute_s):.3f}")
 
     # the full policy panel: 6 policies x 4 prices x 4 budgets, ONE program
     policies = list(POLICY_WEIGHTS)

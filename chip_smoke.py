@@ -65,6 +65,7 @@ def replay_grid(seed: int, n_objects: int, n_requests: int) -> None:
     from repro.core import Trace, simulate, zipf_trace
     from repro.core.policies_jax import POLICY_WEIGHTS, sweep_jax
     from repro.kernels import ops
+    from repro.obs import Tracer
 
     ids = zipf_trace(n_objects=n_objects, n_requests=n_requests,
                      seed=seed).ids
@@ -79,24 +80,28 @@ def replay_grid(seed: int, n_objects: int, n_requests: int) -> None:
     log(f"A: N={n_objects} T={n_requests} grid={len(policies)}x"
         f"{len(cost_matrix)}x{len(budgets)}")
 
-    prof_k: dict = {}
-    t0 = time.perf_counter()
-    got = sweep_jax(policies, ids, cost_matrix, budgets,
-                    num_objects=n_objects, profile=prof_k)
-    log(f"A: kernel path wall_s={time.perf_counter() - t0:.3f} "
-        f"compile_s={prof_k['compile_s']:.3f} "
-        f"execute_s={prof_k['execute_s']:.3f} "
-        f"tpu_custom_call={prof_k['mosaic_kernels']}")
-    if ops.on_tpu():   # a CPU rehearsal runs the Pallas interpreter
-        check(prof_k["mosaic_kernels"] > 0, "grid ran without the kernel")
+    def run(use_pallas):
+        """One grid answer; its dollars and the program's kernel count."""
+        tracer = Tracer()
+        t0 = time.perf_counter()
+        out = sweep_jax(policies, ids, cost_matrix, budgets,
+                        num_objects=n_objects, use_pallas=use_pallas,
+                        tracer=tracer)
+        wall = time.perf_counter() - t0
+        dur = {sp.name: sp.dur for sp in tracer.spans()}
+        kernels = sum(sp.attrs["mosaic_kernels"]
+                      for sp in tracer.spans(name="replay.compile"))
+        log(f"A: {'jnp' if use_pallas is False else 'kernel'} path "
+            f"wall_s={wall:.3f} compile_s="
+            f"{dur.get('replay.lower', 0.0) + dur.get('replay.compile', 0.0):.3f}"
+            f" execute_s={dur['replay.execute']:.3f} "
+            f"tpu_custom_call={kernels}")
+        return out, kernels
 
-    prof_j: dict = {}
-    t0 = time.perf_counter()
-    want = sweep_jax(policies, ids, cost_matrix, budgets,
-                     num_objects=n_objects, use_pallas=False, profile=prof_j)
-    log(f"A: jnp path wall_s={time.perf_counter() - t0:.3f} "
-        f"compile_s={prof_j['compile_s']:.3f} "
-        f"execute_s={prof_j['execute_s']:.3f}")
+    got, kernels = run(None)
+    if ops.on_tpu():   # a CPU rehearsal runs the Pallas interpreter
+        check(kernels > 0, "grid ran without the kernel")
+    want, _ = run(False)
     check(got.shape == (len(policies), len(cost_matrix), len(budgets))
           and np.isfinite(got).all(), f"grid result shape {got.shape}")
     np.testing.assert_array_equal(got, want)

@@ -26,6 +26,12 @@ kernel on TPU backends (`use_pallas=None` -> `on_tpu()`), the pure-jnp
 reduction elsewhere; both implement the same lexicographic argmin and are
 checked step-for-step against each other in tests/test_policies_jax.py.
 
+Each scan step runs in three `jax.named_scope`s (`STEP_SCOPES`): the score
+pass, victim selection and the state update. `step_scopes()` maps the
+compiled grid's instructions to them, so a profiler trace's device time
+splits by phase of the step; `sweep_jax(tracer=...)` records the host
+phases of each call as spans (DESIGN.md §9).
+
 Uniform-size mode (the paper's exact-reference regime): one eviction per
 miss, no data-dependent loop. Variable sizes stay on the host reference
 (`policies.py`); see DESIGN.md §3.
@@ -34,9 +40,9 @@ Validated step-for-step against `policies.py` in tests/test_policies_jax.py.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
-import time
 from collections.abc import Sequence
 
 import jax
@@ -46,10 +52,18 @@ import numpy as np
 from .trace import next_use_indices
 from ..kernels import ops
 
-__all__ = ["PolicyWeights", "POLICY_WEIGHTS", "simulate_jax", "sweep_jax",
-           "stack_policy_weights"]
+__all__ = ["PolicyWeights", "POLICY_WEIGHTS", "STEP_SCOPES", "simulate_jax",
+           "sweep_jax", "stack_policy_weights", "step_scopes"]
 
 _BIG = jnp.float32(3.4e38)
+# the named scopes that partition the scan step: the touch bookkeeping and
+# the score pass, victim selection, and the state update
+STEP_SCOPES = ("replay.score", "replay.victim", "replay.update")
+# grid programs compiled ahead of their first call, by (argument shapes,
+# num_objects, use_pallas); the oldest goes when a new shape would pass
+# the bound
+_EXECUTABLES: dict = {}
+_MAX_EXECUTABLES = 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -128,41 +142,45 @@ def _simulate(ids, nxt, costs, sizes, capacity, weights, num_objects: int,
     def step(state, inp):
         cached, static, stored_nxt, touch, freq, used, infl, dollars, hits = state
         t, i, nu = inp
-        tf = t.astype(jnp.float32)
-        freq = freq.at[i].add(1)
-        is_hit = cached[i]
-        dollars = dollars + jnp.where(is_hit, 0.0, costs[i])
-        hits = hits + is_hit.astype(jnp.int32)
-
-        # victim: lexicographic argmin of (score, last_touch) among cached\{i}
-        mask = cached.at[i].set(False)
-        raw = total_scores(static, stored_nxt, tf)
-        if use_pallas:
-            victim, victim_score = ops.evict_argmin(raw, touch, mask,
-                                                    use_pallas=True)
-        else:
-            scores = jnp.where(mask, raw, _BIG)
-            min_s = jnp.min(scores)
-            tie = scores <= min_s  # exact equality; _BIG rows excluded by min
-            victim = jnp.argmin(jnp.where(tie, touch, INT_BIG))
-            victim_score = scores[victim]
-        full = used >= capacity
-
-        # eq.-(2) semantics: a miss always inserts (mandatory displacement)
-        do_insert = ~is_hit
-        do_evict = do_insert & full & (victim_score < _BIG)
-        cached = cached.at[victim].set(jnp.where(do_evict, False, cached[victim]))
-        # GreedyDual aging: L := priority of the evicted victim
-        gd_active = (weights[2] + weights[3]) > 0
-        infl = jnp.where(do_evict & gd_active, victim_score, infl)
-        my_static = _static_score(weights, tf, freq[i].astype(jnp.float32),
-                                  infl, c_over_s[i])
-        used = used - jnp.where(do_evict, 1, 0) + jnp.where(do_insert, 1, 0)
-        cached = cached.at[i].set(cached[i] | do_insert)
-        # touches (hit or insert) refresh score, next-use and touch time
-        static = static.at[i].set(my_static)
-        stored_nxt = stored_nxt.at[i].set(nu)
-        touch = touch.at[i].set(t)
+        with jax.named_scope("replay.score"):
+            tf = t.astype(jnp.float32)
+            freq = freq.at[i].add(1)
+            is_hit = cached[i]
+            dollars = dollars + jnp.where(is_hit, 0.0, costs[i])
+            hits = hits + is_hit.astype(jnp.int32)
+            # victim: lexicographic argmin of (score, last_touch) among
+            # cached\{i}
+            mask = cached.at[i].set(False)
+            raw = total_scores(static, stored_nxt, tf)
+        with jax.named_scope("replay.victim"):
+            if use_pallas:
+                victim, victim_score = ops.evict_argmin(raw, touch, mask,
+                                                        use_pallas=True)
+            else:
+                scores = jnp.where(mask, raw, _BIG)
+                min_s = jnp.min(scores)
+                tie = scores <= min_s  # exact equality; _BIG rows excluded
+                victim = jnp.argmin(jnp.where(tie, touch, INT_BIG))
+                victim_score = scores[victim]
+        with jax.named_scope("replay.update"):
+            full = used >= capacity
+            # eq.-(2) semantics: a miss always inserts (mandatory
+            # displacement)
+            do_insert = ~is_hit
+            do_evict = do_insert & full & (victim_score < _BIG)
+            cached = cached.at[victim].set(
+                jnp.where(do_evict, False, cached[victim]))
+            # GreedyDual aging: L := priority of the evicted victim
+            gd_active = (weights[2] + weights[3]) > 0
+            infl = jnp.where(do_evict & gd_active, victim_score, infl)
+            my_static = _static_score(weights, tf, freq[i].astype(jnp.float32),
+                                      infl, c_over_s[i])
+            used = used - jnp.where(do_evict, 1, 0) + jnp.where(do_insert, 1, 0)
+            cached = cached.at[i].set(cached[i] | do_insert)
+            # touches (hit or insert) refresh score, next-use and touch time
+            static = static.at[i].set(my_static)
+            stored_nxt = stored_nxt.at[i].set(nu)
+            touch = touch.at[i].set(t)
         new_state = (cached, static, stored_nxt, touch, freq, used, infl,
                      dollars, hits)
         return new_state, ((dollars, hits) if trace_steps else None)
@@ -224,8 +242,7 @@ def _sweep_grid(weight_stack, ids, nxt, cost_matrix, sizes, budgets,
 def sweep_jax(policy, ids: np.ndarray, cost_matrix: np.ndarray,
               budgets: np.ndarray, num_objects: int | None = None,
               sizes: np.ndarray | None = None,
-              use_pallas: bool | None = None,
-              profile: dict | None = None) -> np.ndarray:
+              use_pallas: bool | None = None, tracer=None) -> np.ndarray:
     """Batched replay of a (policy x price-vector x budget) grid on device.
 
     policy:      one policy name -> dollars of shape (P, K);
@@ -234,42 +251,81 @@ def sweep_jax(policy, ids: np.ndarray, cost_matrix: np.ndarray,
                  policies replayed inside the SAME compiled scan program.
     cost_matrix: (P, N) per-object costs for P price vectors.
     budgets:     (K,) page budgets.
-    profile:     pass a dict to get compile time separated from execute
-                 time (DESIGN.md §9): filled with `compile_s` (trace +
-                 lower + XLA compile, ~0 when the executable is already
-                 cached), `execute_s` (device run, block_until_ready) and
-                 `mosaic_kernels`, the compiled program's count of
-                 Mosaic kernel calls (0 for the jnp path or the Pallas
-                 interpreter).
+    tracer:      an `obs.Tracer` to record the call's phases as spans
+                 (DESIGN.md §9): `replay.prepare` (next-use indices and host
+                 arrays), `replay.transfer` (host to device), `replay.lower`
+                 and `replay.compile` (first call of a shape only; the
+                 compile span carries `cells`, `steps`, `mosaic_kernels`
+                 and `scopes`, the step's `scope_map`), `replay.execute`
+                 (dispatch until the output is ready) and `replay.fetch`
+                 (device to host). None records nothing and builds no map.
+
+    A shape is lowered and compiled (or loaded from the persistent cache)
+    ahead of its first call, which makes compiling a phase of its own and
+    its text readable; jit's dispatch then runs that same executable.
     """
-    single = isinstance(policy, str)
-    if single:
-        stack = stack_policy_weights([policy])
-    elif isinstance(policy, np.ndarray) or isinstance(policy, jax.Array):
-        stack = np.asarray(policy, dtype=np.float32)
-        if stack.ndim != 2 or stack.shape[1] != 6:
-            raise ValueError("weight stack must have shape (Q, 6)")
-    else:
-        stack = stack_policy_weights(policy)
-    ids = np.asarray(ids, dtype=np.int32)
-    n = int(num_objects if num_objects is not None else ids.max() + 1)
-    nxt = jnp.asarray(next_use_indices(ids).astype(np.int32))
-    s = jnp.ones(n, jnp.float32) if sizes is None else jnp.asarray(sizes, jnp.float32)
-    args = (jnp.asarray(stack), jnp.asarray(ids), nxt,
-            jnp.asarray(cost_matrix, dtype=jnp.float32), s,
-            jnp.asarray(budgets, dtype=jnp.int32))
-    up = _resolve_use_pallas(use_pallas)
-    if profile is None:
-        out = _sweep_grid(*args, n, up)
-    else:
-        t0 = time.perf_counter()
-        compiled = _sweep_grid.lower(*args, n, up).compile()
-        t1 = time.perf_counter()
-        out = jax.block_until_ready(compiled(*args))
-        t2 = time.perf_counter()
-        profile.update(compile_s=t1 - t0, execute_s=t2 - t1,
-                       cells=int(out.size),
-                       mosaic_kernels=compiled.as_text().count(
-                           "tpu_custom_call"))
-    out = np.asarray(out)
+    span = tracer.span if tracer else _no_span
+    with span("replay.prepare", cat="replay"):
+        single = isinstance(policy, str)
+        if single:
+            stack = stack_policy_weights([policy])
+        elif isinstance(policy, np.ndarray) or isinstance(policy, jax.Array):
+            stack = np.asarray(policy, dtype=np.float32)
+            if stack.ndim != 2 or stack.shape[1] != 6:
+                raise ValueError("weight stack must have shape (Q, 6)")
+        else:
+            stack = stack_policy_weights(policy)
+        ids = np.asarray(ids, dtype=np.int32)
+        n = int(num_objects if num_objects is not None else ids.max() + 1)
+        nxt = next_use_indices(ids).astype(np.int32)
+        up = _resolve_use_pallas(use_pallas)
+    with span("replay.transfer", cat="replay"):
+        s = (jnp.ones(n, jnp.float32) if sizes is None
+             else jnp.asarray(sizes, jnp.float32))
+        args = (jnp.asarray(stack), jnp.asarray(ids), jnp.asarray(nxt),
+                jnp.asarray(cost_matrix, dtype=jnp.float32), s,
+                jnp.asarray(budgets, dtype=jnp.int32))
+        if tracer:
+            jax.block_until_ready(args)
+    key = (tuple((a.shape, a.dtype) for a in args), n, up)
+    if key not in _EXECUTABLES:
+        with span("replay.lower", cat="replay"):
+            lowered = _sweep_grid.lower(*args, n, up)
+        with span("replay.compile", cat="replay") as sp:
+            compiled = lowered.compile()
+            if tracer:
+                text = compiled.as_text()
+                sp.set(cells=len(stack) * len(cost_matrix) * len(budgets),
+                       steps=len(ids),
+                       mosaic_kernels=text.count("tpu_custom_call"),
+                       scopes=_scope_map(text))
+        if len(_EXECUTABLES) >= _MAX_EXECUTABLES:
+            del _EXECUTABLES[next(iter(_EXECUTABLES))]
+        _EXECUTABLES[key] = compiled
+    with span("replay.execute", cat="replay"):
+        out = jax.block_until_ready(_sweep_grid(*args, n, up))
+    with span("replay.fetch", cat="replay"):
+        out = np.asarray(out)
     return out[0] if single else out
+
+
+def _no_span(name: str, cat: str = "span"):
+    return contextlib.nullcontext()
+
+
+def _scope_map(hlo: str) -> dict[str, list[str]]:
+    from ..launch.hlo_analysis import scope_map
+    return scope_map(hlo, STEP_SCOPES)
+
+
+def step_scopes() -> dict[str, list[str]]:
+    """Scope map of the grid program compiled last: each of `STEP_SCOPES`,
+    and `unscoped`, -> the names of the compiled instructions it covers.
+
+    A profiler names each device op event by its instruction, so the map
+    turns a trace's op times into device time per phase of the scan step.
+    Empty before any grid has compiled; built only when asked.
+    """
+    if not _EXECUTABLES:
+        return {}
+    return _scope_map(next(reversed(_EXECUTABLES.values())).as_text())

@@ -1,4 +1,5 @@
-"""Post-optimization HLO analysis: collective bytes per device.
+"""Post-optimization HLO analysis: collective bytes per device, and the
+named scope of each instruction that runs on the device.
 
 cost_analysis() gives FLOPs and memory bytes but NOT collective traffic;
 we parse compiled.as_text() instead (the prompt's prescribed method).
@@ -18,7 +19,7 @@ import dataclasses
 import re
 from collections import defaultdict
 
-__all__ = ["CollectiveStats", "analyze_collectives"]
+__all__ = ["CollectiveStats", "analyze_collectives", "scope_map"]
 
 
 _DTYPE_BYTES = {
@@ -180,3 +181,88 @@ def analyze_collectives(hlo: str) -> CollectiveStats:
         return CollectiveStats(dict(by_b), dict(by_c))
     by_b, by_c = visit(entry)
     return CollectiveStats(by_b, by_c)
+
+_INSTRUCTION = re.compile(r"(?:ROOT\s+)?%([\w.\-]+)\s*=\s*")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_NAME = re.compile(r"%([\w.\-]+)")
+
+
+def _group_end(text: str, i: int) -> int:
+    """Index just past the bracket group that opens at text[i]."""
+    depth = 0
+    for j in range(i, len(text)):
+        if text[j] in "([{":
+            depth += 1
+        elif text[j] in ")]}":
+            depth -= 1
+            if depth == 0:
+                return j + 1
+    return len(text)
+
+
+def _operands(rest: str) -> list[str]:
+    """Operand names of an instruction, from the text after its ' = '."""
+    i = _group_end(rest, 0) if rest.startswith("(") else rest.find(" ")
+    j = rest.find("(", max(i, 0))
+    return _NAME.findall(rest[j:_group_end(rest, j)]) if j >= 0 else []
+
+
+def scope_map(hlo: str, scopes) -> dict[str, list[str]]:
+    """Named scope -> names of the instructions it covers, plus `unscoped`.
+
+    Reads the instructions that run as device ops: those of the entry
+    computation and of every while body and condition reached from it,
+    not the internals of fused computations. An instruction takes the
+    innermost of `scopes` named in its `op_name` metadata. One without an
+    `op_name` (a copy or reshape that layout assignment inserted, say)
+    takes the scope of its only user, failing that of its first operand,
+    failing that it is `unscoped`; so is one whose `op_name` names none
+    of `scopes`.
+    """
+    comps = _split_computations(hlo)
+    todo, seen = [_entry_name(hlo)], set()
+    insts: dict[str, tuple] = {}            # name -> (op_name, operands)
+    users: dict[str, list[str]] = defaultdict(list)
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in comps:
+            continue
+        seen.add(comp)
+        todo += [c for c, kind in _calls(comps[comp])
+                 if kind in ("body", "condition")]
+        for ls in comps[comp]:
+            m = _INSTRUCTION.match(ls)
+            if not m:
+                continue
+            rest = ls[m.end():]
+            op = _OP_NAME.search(rest)
+            ops = _operands(rest)
+            insts[m.group(1)] = (op.group(1) if op else "", ops)
+            for o in ops:
+                users[o].append(m.group(1))
+    wanted = set(scopes)
+    memo: dict[str, str] = {}
+
+    def resolve(name: str, visiting: frozenset = frozenset()) -> str:
+        if name in memo:
+            return memo[name]
+        if name not in insts or name in visiting:
+            return "unscoped"
+        op_name, ops = insts[name]
+        if op_name:
+            named = [p for p in op_name.split("/") if p in wanted]
+            memo[name] = named[-1] if named else "unscoped"
+            return memo[name]
+        visiting = visiting | {name}
+        got = "unscoped"
+        if len(users[name]) == 1:
+            got = resolve(users[name][0], visiting)
+        if got == "unscoped" and ops:
+            got = resolve(ops[0], visiting)
+        memo[name] = got
+        return got
+
+    out: dict[str, list[str]] = {s: [] for s in (*scopes, "unscoped")}
+    for name in insts:
+        out[resolve(name)].append(name)
+    return out

@@ -7,22 +7,22 @@ dollar attribution (`dollars = f + bytes * e`, the same float the
 dollars for a consumer reproduces that consumer's meter total to float
 tolerance (asserted in tests/test_obs.py).
 
+While a profiler capture runs, each span also opens a
+`jax.profiler.TraceAnnotation` of its name, so the program's spans are
+host events of the same trace as the device ops, on one clock: a device
+idle gap can be charged to the span the host was in.
+
 Publishers hold the tracer duck-typed (`repro.egress` never imports this
 module) and guard the hot path with plain truthiness: `NullTracer` (and a
 disabled `Tracer`) are falsy, so `if tracer:` costs one branch and the
 disabled overhead is ~0 (measured in bench_policy_throughput).
 
-Exports: JSON (list of span dicts), Chrome trace-event format — complete
-events (`"ph": "X"`) loadable in Perfetto / chrome://tracing — and
-OTLP-shaped JSON (`resourceSpans`/`scopeSpans`, `to_otlp`) for collectors
-that speak OpenTelemetry.
+Exports: JSON (list of span dicts) and Chrome trace-event format —
+complete events (`"ph": "X"`) loadable in Perfetto / chrome://tracing.
 
 Span recording is bounded: the tracer keeps at most `max_spans` finished
 spans (a ring; `dropped` counts the overflow), so tracing a long-running
-server never grows without bound. For full-fidelity capture past the ring,
-pass `stream=` a writable file object: every finished span is written
-through as one NDJSON line at close time, so the stream holds spans the
-ring has already evicted.
+server never grows without bound.
 """
 from __future__ import annotations
 
@@ -34,6 +34,8 @@ import pathlib
 import threading
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Span", "Tracer", "NullTracer", "regime_tag"]
 
@@ -49,7 +51,7 @@ class Span:
     `regime`, `consumer`, ...)."""
 
     __slots__ = ("name", "cat", "span_id", "parent_id", "t0", "dur", "tid",
-                 "attrs", "_tracer")
+                 "attrs", "_tracer", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  span_id: int, parent_id: Optional[int], t0: float):
@@ -62,6 +64,10 @@ class Span:
         self.tid = 0
         self.attrs: Optional[dict] = None
         self._tracer = tracer
+        self._annotation = None
+        if TraceAnnotation.is_enabled():      # a profiler capture is on
+            self._annotation = TraceAnnotation(name)
+            self._annotation.__enter__()
 
     def set(self, **attrs) -> "Span":
         if self.attrs is None:
@@ -87,21 +93,15 @@ class Span:
 class Tracer:
     """Span recorder with a per-thread open-span stack (nesting)."""
 
-    def __init__(self, max_spans: int = 100_000, enabled: bool = True,
-                 stream=None):
+    def __init__(self, max_spans: int = 100_000, enabled: bool = True):
         self.enabled = enabled
         self.max_spans = int(max_spans)
-        # perf_counter drives durations; the wall-clock epoch captured
-        # alongside it anchors OTLP's unix-nano timestamps
         self._epoch = time.perf_counter()
-        self._epoch_unix = time.time()
         self._spans: collections.deque[Span] = collections.deque(
             maxlen=self.max_spans)
         self._recorded = 0
         self._next_id = 1
         self._local = threading.local()
-        self.stream = stream          # NDJSON write-through of closed spans
-        self._stream_lock = threading.Lock()
 
     def __bool__(self) -> bool:
         return self.enabled
@@ -137,6 +137,9 @@ class Tracer:
 
     def _close(self, sp: Span) -> None:
         sp.dur = (time.perf_counter() - self._epoch) - sp.t0
+        if sp._annotation is not None:
+            sp._annotation.__exit__(None, None, None)
+            sp._annotation = None
         sp.tid = threading.get_ident()
         st = getattr(self._local, "stack", None) or ()
         if st and st[-1] is sp:
@@ -147,10 +150,6 @@ class Tracer:
                     break
         self._spans.append(sp)
         self._recorded += 1
-        if self.stream is not None:
-            line = json.dumps(sp.to_dict(), sort_keys=True)
-            with self._stream_lock:
-                self.stream.write(line + "\n")
 
     end = _close   # public pair of `begin()`
 
@@ -202,46 +201,10 @@ class Tracer:
                           parent_id=sp.parent_id)))
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
-    def to_otlp(self, service_name: str = "repro") -> dict:
-        """OTLP/JSON-shaped export (`resourceSpans`/`scopeSpans`), the
-        schema OpenTelemetry collectors ingest: ids are zero-padded hex
-        (spanId 16, traceId 32), times are unix-nano strings anchored at
-        the wall-clock epoch captured next to the perf_counter epoch, and
-        attrs map to typed `AnyValue`s. One tracer = one trace."""
-        trace_id = f"{os.getpid() & 0xFFFFFFFFFFFFFFFF:016x}" \
-                   f"{int(self._epoch_unix * 1e6) & 0xFFFFFFFFFFFFFFFF:016x}"
-        spans = []
-        for sp in self._spans:
-            start = int((self._epoch_unix + sp.t0) * 1e9)
-            attrs = [_otlp_attr("span.cat", sp.cat)]
-            for k, v in (sp.attrs or {}).items():
-                attrs.append(_otlp_attr(k, v))
-            spans.append(dict(
-                traceId=trace_id,
-                spanId=f"{sp.span_id & 0xFFFFFFFFFFFFFFFF:016x}",
-                parentSpanId=(f"{sp.parent_id & 0xFFFFFFFFFFFFFFFF:016x}"
-                              if sp.parent_id is not None else ""),
-                name=sp.name, kind=1,           # SPAN_KIND_INTERNAL
-                startTimeUnixNano=str(start),
-                endTimeUnixNano=str(start + int(sp.dur * 1e9)),
-                attributes=attrs))
-        return {"resourceSpans": [{
-            "resource": {"attributes": [
-                _otlp_attr("service.name", service_name)]},
-            "scopeSpans": [{"scope": {"name": "repro.obs"},
-                            "spans": spans}],
-        }]}
-
     def write_json(self, path) -> pathlib.Path:
         path = pathlib.Path(path)
         path.parent.mkdir(parents=True, exist_ok=True)
         path.write_text(self.to_json() + "\n")
-        return path
-
-    def write_otlp(self, path, service_name: str = "repro") -> pathlib.Path:
-        path = pathlib.Path(path)
-        path.parent.mkdir(parents=True, exist_ok=True)
-        path.write_text(json.dumps(self.to_otlp(service_name)) + "\n")
         return path
 
     def write_chrome_trace(self, path) -> pathlib.Path:
@@ -249,19 +212,6 @@ class Tracer:
         path.parent.mkdir(parents=True, exist_ok=True)
         path.write_text(json.dumps(self.to_chrome_trace()) + "\n")
         return path
-
-
-def _otlp_attr(key: str, v) -> dict:
-    """One OTLP KeyValue; bool checked before int (bool is an int)."""
-    if isinstance(v, bool):
-        value = {"boolValue": v}
-    elif isinstance(v, int):
-        value = {"intValue": str(v)}       # OTLP/JSON carries i64 as string
-    elif isinstance(v, float):
-        value = {"doubleValue": v}
-    else:
-        value = {"stringValue": str(v)}
-    return {"key": key, "value": value}
 
 
 class _NullSpan:
@@ -308,6 +258,3 @@ class NullTracer:
 
     def to_chrome_trace(self) -> dict:
         return {"traceEvents": [], "displayTimeUnit": "ms"}
-
-    def to_otlp(self, service_name: str = "repro") -> dict:
-        return {"resourceSpans": []}

@@ -98,15 +98,20 @@ class ServeEngine:
         """Run prefill; persist each row's prefix KV to the object store so
         identical prefixes can be re-fetched (billed) or served from the
         local egress cache."""
-        logits, caches = self.model.prefill(
-            self.params, {"tokens": jnp.asarray(prompts)})
-        for b in range(prompts.shape[0]):
-            key = _prefix_key(prompts[b])
-            if not self.store.contains(key):
-                # store one row's KV bytes (serialized, billed on re-fetch)
-                row = [np.asarray(kv[0][b]) for kv in caches]
-                blob = b"".join(r.tobytes() for r in row)
-                self.store.put(key, blob)
+        with self._span("serve.prefill", batch=prompts.shape[0]):
+            logits, caches = self.model.prefill(
+                self.params, {"tokens": jnp.asarray(prompts)})
+            if self.tracer:     # the span ends when the outputs are ready
+                jax.block_until_ready((logits, caches))
+        with self._span("serve.kv_persist"):
+            for b in range(prompts.shape[0]):
+                key = _prefix_key(prompts[b])
+                if not self.store.contains(key):
+                    # store one row's KV bytes (serialized, billed on
+                    # re-fetch)
+                    row = [np.asarray(kv[0][b]) for kv in caches]
+                    blob = b"".join(r.tobytes() for r in row)
+                    self.store.put(key, blob)
         return logits, caches
 
     def _span(self, name: str, **attrs):
@@ -137,20 +142,20 @@ class ServeEngine:
                             self.fleet.access(key)
                         else:
                             self.cache.get(key)
-            with self._span("serve.prefill", batch=len(group)):
-                logits, caches = self._prefill_batch(prompts)
+            logits, caches = self._prefill_batch(prompts)
             S = prompts.shape[1]
             max_new = max(r.max_new_tokens for r in group)
             caches = _grow(self.model, caches, S + max_new)
             tok = jnp.argmax(logits, -1).astype(jnp.int32)
             outs = [tok]
+            # the span ends when the tokens are on the host
             with self._span("serve.decode", batch=len(group), steps=max_new):
                 for step in range(max_new - 1):
                     logits, caches = self._decode(self.params, tok, caches,
                                                   jnp.int32(S + step))
                     tok = jnp.argmax(logits, -1).astype(jnp.int32)
                     outs.append(tok)
-            gen = np.stack([np.asarray(t) for t in outs], 1)
+                gen = np.stack([np.asarray(t) for t in outs], 1)
             for i, r in enumerate(group):
                 r.output = gen[i][:r.max_new_tokens]
 

@@ -275,16 +275,65 @@ def test_opt_exact_profile_counters():
 
 
 def test_sweep_jax_profile_compile_execute_split():
+    """sweep_jax's spans: the first call of a shape lowers and compiles
+    between transfer and execute; a second call reuses the executable."""
     from repro.core.policies_jax import sweep_jax
     rng = np.random.default_rng(4)
     ids = rng.integers(0, 20, 200).astype(np.int32)
     cost_matrix = np.stack([rng.uniform(0.5, 2.0, 20) for _ in range(2)])
     budgets = np.array([2, 4])
-    prof = {}
+    tracer = Tracer()
     out = sweep_jax("gdsf", ids, cost_matrix, budgets, num_objects=20,
-                    profile=prof)
-    assert prof["compile_s"] >= 0 and prof["execute_s"] >= 0
-    assert prof["cells"] == out.size == 4
+                    tracer=tracer)
+    first = [sp.name for sp in tracer.spans()]
+    assert first == ["replay.prepare", "replay.transfer", "replay.lower",
+                     "replay.compile", "replay.execute", "replay.fetch"]
+    assert all(sp.cat == "replay" and sp.dur >= 0 for sp in tracer.spans())
+    compile_attrs = tracer.spans(name="replay.compile")[0].attrs
+    assert compile_attrs["cells"] == out.size == 4
+    assert compile_attrs["steps"] == 200
+    assert compile_attrs["mosaic_kernels"] == 0
+    again = sweep_jax("gdsf", ids, cost_matrix, budgets, num_objects=20,
+                      tracer=tracer)
+    assert [sp.name for sp in tracer.spans()][len(first):] == [
+        "replay.prepare", "replay.transfer", "replay.execute",
+        "replay.fetch"]
+    untraced = sweep_jax("gdsf", ids, cost_matrix, budgets, num_objects=20)
+    np.testing.assert_array_equal(out, again)
+    np.testing.assert_array_equal(out, untraced)
+
+
+def test_tracer_span_is_a_host_event_of_a_profiler_capture(tmp_path):
+    """With a capture on, a span is a host event of the profiler's own
+    trace, nested in the annotation around it, on the profiler's clock."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    f = jax.jit(lambda x: (x @ x).sum())
+    x = jax.numpy.ones((256, 256))
+    f(x).block_until_ready()
+    tracer = Tracer()
+    with tracer.span("before.capture"):
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TraceAnnotation("outer.window"):
+            with tracer.span("replay.execute", cat="replay"):
+                f(x).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {ev.name: (ev.start_ns, ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+    assert "before.capture" not in host
+    (s, d), (ws, wd) = host["replay.execute"], host["outer.window"]
+    assert ws <= s and s + d <= ws + wd
+    sp, = tracer.spans(name="replay.execute")
+    assert d / 1e9 == pytest.approx(sp.dur, rel=0.5, abs=2e-3)
 
 
 # ---------------------------------------------------------------------------
@@ -382,57 +431,3 @@ def test_governed_serve_span_dollars_equal_meter():
         assert by_id[s.parent_id].name in ("serve.request", "serve.batch")
     snap = engine.governance_snapshot()
     assert "events" in snap and "spans" in snap
-
-
-# ---------------------------------------------------------------------------
-# NDJSON stream write-through + OTLP export
-
-
-def test_tracer_stream_writes_through_ring_eviction():
-    import io
-    buf = io.StringIO()
-    t = Tracer(max_spans=3, stream=buf)
-    for i in range(10):
-        with t.span(f"op{i}", cat="w", dollars=0.125 * i):
-            pass
-    assert t.dropped == 7                       # ring kept only the last 3
-    lines = [json.loads(ln) for ln in buf.getvalue().splitlines()]
-    assert [d["name"] for d in lines] == [f"op{i}" for i in range(10)]
-    assert lines[4]["args"]["dollars"] == 0.5   # evicted span survived
-
-
-def test_tracer_otlp_export_shape():
-    t = Tracer()
-    with t.span("outer", cat="test", consumer="c", dollars=0.25,
-                nbytes=4096, hit=False):
-        with t.span("inner", cat="test"):
-            pass
-    o = t.to_otlp(service_name="svc")
-    res = o["resourceSpans"][0]
-    assert {"key": "service.name", "value": {"stringValue": "svc"}} \
-        in res["resource"]["attributes"]
-    spans = res["scopeSpans"][0]["spans"]
-    assert len(spans) == 2
-    by_name = {s["name"]: s for s in spans}
-    inner, outer = by_name["inner"], by_name["outer"]
-    for s in spans:                             # OTLP id + time invariants
-        assert re.fullmatch(r"[0-9a-f]{32}", s["traceId"])
-        assert re.fullmatch(r"[0-9a-f]{16}", s["spanId"])
-        assert int(s["endTimeUnixNano"]) >= int(s["startTimeUnixNano"]) > 0
-    assert inner["parentSpanId"] == outer["spanId"]     # nesting preserved
-    assert outer["parentSpanId"] == ""
-    attrs = {a["key"]: a["value"] for a in outer["attributes"]}
-    assert attrs["dollars"] == {"doubleValue": 0.25}
-    assert attrs["nbytes"] == {"intValue": "4096"}      # i64 rides as string
-    assert attrs["hit"] == {"boolValue": False}
-    assert attrs["consumer"] == {"stringValue": "c"}
-    json.dumps(o)                               # fully JSON-serializable
-    assert NullTracer().to_otlp() == {"resourceSpans": []}
-
-
-def test_tracer_write_otlp_file(tmp_path):
-    t = Tracer()
-    with t.span("op", cat="t"):
-        pass
-    p = t.write_otlp(tmp_path / "otlp.json")
-    assert json.loads(p.read_text())["resourceSpans"]

@@ -121,3 +121,82 @@ def test_pallas_victim_path_full_api():
     out_p = sweep_jax(["lru", "gdsf"], ids, costs[None, :], np.array([3, 6]),
                       num_objects=12, use_pallas=True)
     np.testing.assert_array_equal(out_j, out_p)
+
+
+def test_step_scopes_cover_the_compiled_loop_cpu():
+    """On the jnp path, every instruction of the compiled grid's entry and
+    loop computations lands in one of the step's three scopes or in
+    `unscoped` by the stated rule, and each scope holds device work."""
+    import re
+
+    import repro.core.policies_jax as pj
+    from repro.launch.hlo_analysis import (_INSTRUCTION, _OP_NAME, _calls,
+                                           _split_computations)
+    from repro.obs import Tracer
+    rng = np.random.default_rng(11)
+    ids, costs = _rand(rng, 90, 30)
+    tracer = Tracer()
+    sweep_jax(list(POLICY_WEIGHTS), ids, np.stack([costs, 3 * costs]),
+              np.array([3, 9]), num_objects=30, use_pallas=False,
+              tracer=tracer)
+    scopes = tracer.spans(name="replay.compile")[0].attrs["scopes"]
+    assert pj.step_scopes() == scopes
+    assert set(scopes) == {*pj.STEP_SCOPES, "unscoped"}
+    where = {n: s for s, names in scopes.items() for n in names}
+    assert len(where) == sum(map(len, scopes.values()))
+    text = next(reversed(pj._EXECUTABLES.values())).as_text()
+    comps = _split_computations(text)
+    entry = re.search(r"ENTRY\s+%?([\w.\-]+)", text).group(1)
+    loops = [c for c, kind in _calls(comps[entry])
+             if kind in ("body", "condition")]
+    assert loops
+    for comp in [entry, *loops]:
+        for ls in comps[comp]:
+            m = _INSTRUCTION.match(ls)
+            if not m:
+                continue
+            op = _OP_NAME.search(ls)
+            named = [p for p in (op.group(1) if op else "").split("/")
+                     if p in pj.STEP_SCOPES]
+            if named:
+                assert where[m.group(1)] == named[-1], ls
+            elif op and op.group(1):
+                assert where[m.group(1)] == "unscoped", ls
+            else:
+                assert m.group(1) in where, ls
+    assert any("reduce" in n for n in scopes["replay.victim"])
+    assert all(scopes[s] for s in pj.STEP_SCOPES)
+
+
+def test_no_tracer_builds_no_scope_map_and_opens_no_span(monkeypatch):
+    """No tracer, a NullTracer or a disabled Tracer: the first call of a
+    shape compiles without building the scope map, and nothing is
+    recorded."""
+    import repro.core.policies_jax as pj
+    from repro.obs import NullTracer, Tracer
+
+    def refuse(hlo):
+        raise AssertionError("scope map built without a tracer")
+    monkeypatch.setattr(pj, "_scope_map", refuse)
+    monkeypatch.setattr(pj, "_EXECUTABLES", {})
+    rng = np.random.default_rng(12)
+    ids, costs = _rand(rng, 60, 17)
+    off = Tracer(enabled=False)
+    for tracer in (None, NullTracer(), off):
+        pj._EXECUTABLES.clear()
+        out = sweep_jax("lru", ids, costs[None, :], np.array([2]),
+                        num_objects=17, tracer=tracer)
+        assert out.shape == (1, 1)
+    assert off.spans() == []
+
+
+def test_compiled_grids_are_bounded_and_reused(monkeypatch):
+    import repro.core.policies_jax as pj
+    monkeypatch.setattr(pj, "_EXECUTABLES", {})
+    monkeypatch.setattr(pj, "_MAX_EXECUTABLES", 2)
+    rng = np.random.default_rng(13)
+    ids, costs = _rand(rng, 40, 9)
+    for n in (9, 10, 11, 11):
+        sweep_jax("lfu", ids, np.resize(costs, (1, n)), np.array([2]),
+                  num_objects=n)
+    assert [k[1] for k in pj._EXECUTABLES] == [10, 11]

@@ -87,3 +87,28 @@ def test_fleet_mode_partitions_prefix_cache():
     snap = engine.governance_snapshot()
     assert snap["fleet"]["n_nodes"] == 3
     assert snap["fleet"]["dollars"] == fleet.dollars()
+
+
+def test_prefill_and_kv_persist_spans_nest_under_batch():
+    """Prefill, KV persistence and decode are sibling spans of the batch,
+    in that order; prefill ends when its outputs are ready, decode when
+    the tokens are on the host."""
+    from repro.obs import Tracer
+    from repro.serve.engine import _prefix_key
+    cfg = get_config("phi4-mini-3.8b", smoke=True)
+    model = get_model(cfg)
+    tracer = Tracer()
+    engine = ServeEngine(model, model.init(jax.random.key(0)),
+                         prefix_cache_bytes=1 << 22, tracer=tracer)
+    prompt = np.arange(10, dtype=np.int32)
+    engine.serve([Request(0, prompt, max_new_tokens=3)])
+    assert engine.store.contains(_prefix_key(prompt))
+    batch, = tracer.spans(name="serve.batch")
+    prefill, = tracer.spans(name="serve.prefill")
+    persist, = tracer.spans(name="serve.kv_persist")
+    decode, = tracer.spans(name="serve.decode")
+    for sp in (prefill, persist, decode):
+        assert sp.parent_id == batch.span_id and sp.cat == "serve"
+    assert prefill.t0 + prefill.dur <= persist.t0
+    assert persist.t0 + persist.dur <= decode.t0
+    assert decode.t0 + decode.dur <= batch.t0 + batch.dur

@@ -63,16 +63,52 @@ def test_interval_occupancy_compiles(chip):
         _spec(chip, (262_144,), jnp.float32), interpret=False))
 
 
-def test_replay_grid_compiles_with_kernel(chip, monkeypatch):
+@pytest.fixture(scope="module")
+def grid(chip):
     """The 6 policies x 4 prices x 4 budgets program, with the default
-    kernel choice resolved as on a TPU backend."""
-    monkeypatch.setattr(ops, "on_tpu", lambda: True)
-    use_pallas = policies_jax._resolve_use_pallas(None)
+    kernel choice resolved as on a TPU backend: (use_pallas, lowered,
+    compiled text)."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "on_tpu", lambda: True)
+        use_pallas = policies_jax._resolve_use_pallas(None)
+        n, t = 65_536, 8_192
+        args = (_spec(chip, (6, 6), jnp.float32),
+                _spec(chip, (t,), jnp.int32), _spec(chip, (t,), jnp.int32),
+                _spec(chip, (4, n), jnp.float32),
+                _spec(chip, (n,), jnp.float32), _spec(chip, (4,), jnp.int32))
+        lowered = policies_jax._sweep_grid.lower(*args, n, use_pallas)
+    return use_pallas, lowered, lowered.compile().as_text()
+
+
+def test_replay_grid_compiles_with_kernel(grid):
+    use_pallas, lowered, text = grid
     assert use_pallas
-    n, t = 65_536, 8_192
-    args = (_spec(chip, (6, 6), jnp.float32), _spec(chip, (t,), jnp.int32),
-            _spec(chip, (t,), jnp.int32), _spec(chip, (4, n), jnp.float32),
-            _spec(chip, (n,), jnp.float32), _spec(chip, (4,), jnp.int32))
-    lowered = policies_jax._sweep_grid.lower(*args, n, use_pallas)
     assert np.prod(lowered.out_info.shape) == 96
-    _assert_kernel(lowered)
+    assert "tpu_custom_call" in text
+
+
+def test_replay_grid_kernel_and_relayouts_map_to_victim_scope(grid):
+    """The Mosaic call, the operands it is handed and the relayouts that
+    produce them are device time of the step's victim selection."""
+    from repro.launch.hlo_analysis import (_INSTRUCTION, _operands,
+                                           scope_map)
+    text = grid[2]
+    scopes = scope_map(text, policies_jax.STEP_SCOPES)
+    victim = set(scopes["replay.victim"])
+    lines = {}
+    for ls in map(str.strip, text.splitlines()):
+        m = _INSTRUCTION.match(ls)
+        if m:
+            lines[m.group(1)] = ls[m.end():]
+    kernels = [k for k, rest in lines.items()
+               if 'custom_call_target="tpu_custom_call"' in rest]
+    assert kernels and set(kernels) <= victim
+    feed = {o for k in kernels for o in _operands(lines[k])}
+    feed |= {o for f in feed for o in _operands(lines[f])
+             if lines.get(o, "").split(" ", 1)[-1].startswith(
+                 ("reshape(", "convert(", "copy(", "bitcast("))}
+    assert any(lines[f].split(" ", 1)[-1].startswith("reshape(")
+               for f in feed)
+    assert feed <= victim
+    for scope in policies_jax.STEP_SCOPES:
+        assert scopes[scope]
