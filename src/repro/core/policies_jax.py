@@ -51,6 +51,7 @@ import numpy as np
 
 from .trace import next_use_indices
 from ..kernels import ops
+from ..kernels.layout import LANES, to_tiles
 
 __all__ = ["PolicyWeights", "POLICY_WEIGHTS", "STEP_SCOPES", "simulate_jax",
            "sweep_jax", "stack_policy_weights", "step_scopes"]
@@ -117,6 +118,13 @@ def _simulate(ids, nxt, costs, sizes, capacity, weights, num_objects: int,
     evaluated at eviction time from the stored next-use index). This exactly
     matches the heap key of the Python reference.
 
+    The per-object state is kept as (rows, 128) tiles, object i at
+    (i // 128, i % 128), laid out once before the scan as the victim kernel
+    reads it; `cached` holds int32 0/1 flags, the kernel's mask, and the
+    padding past `num_objects` is never cached. Costs and sizes are tiled
+    for the whole-table score; the bill and the frozen score read one
+    entry a step from the flat costs and c/s.
+
     `use_pallas` routes the victim argmin through the Pallas TPU kernel
     (`kernels.evict_argmin`) instead of the jnp reduction — the replay
     engine's eviction hot path on real TPUs. `trace_steps` additionally
@@ -124,8 +132,13 @@ def _simulate(ids, nxt, costs, sizes, capacity, weights, num_objects: int,
     equivalence tests.
     """
     T = ids.shape[0]
-    n = num_objects
+    if costs.shape != (num_objects,) or sizes.shape != (num_objects,):
+        raise ValueError(f"costs {costs.shape} and sizes {sizes.shape} must "
+                         f"have one entry per object ({num_objects})")
     c_over_s = (costs / jnp.maximum(sizes, 1e-30)).astype(jnp.float32)
+    cost_tiles, _ = to_tiles(costs, ops.EVICT_BLOCK_N, 0.0)
+    size_tiles, _ = to_tiles(sizes, ops.EVICT_BLOCK_N, 1.0)
+    tiles = cost_tiles.shape
     INT_BIG = jnp.int32(2**31 - 1)
 
     def total_scores(static, stored_nxt, t):
@@ -136,7 +149,8 @@ def _simulate(ids, nxt, costs, sizes, capacity, weights, num_objects: int,
         # belady: evict max next-use  -> score -nxt (never-reused = -BIG)
         bel = jnp.where(never, -_BIG, -nxtf)
         # cost-belady: evict max s*gap/c -> score -(s*gap/c)
-        cb = jnp.where(never, -_BIG, -(sizes * gap / jnp.maximum(costs, 1e-30)))
+        cb = jnp.where(never, -_BIG,
+                       -(size_tiles * gap / jnp.maximum(cost_tiles, 1e-30)))
         return static + weights[4] * bel + weights[5] * cb
 
     def step(state, inp):
@@ -144,50 +158,60 @@ def _simulate(ids, nxt, costs, sizes, capacity, weights, num_objects: int,
         t, i, nu = inp
         with jax.named_scope("replay.score"):
             tf = t.astype(jnp.float32)
-            freq = freq.at[i].add(1)
-            is_hit = cached[i]
+            at = (i // LANES, i % LANES)
+            freq = freq.at[at].add(1)
+            # the flag is read as a value of its own: fused into the bill's
+            # per-cell update, the slice made XLA relay the whole table
+            is_hit = jax.lax.optimization_barrier(cached[at]) != 0
             dollars = dollars + jnp.where(is_hit, 0.0, costs[i])
             hits = hits + is_hit.astype(jnp.int32)
-            # victim: lexicographic argmin of (score, last_touch) among
-            # cached\{i}
-            mask = cached.at[i].set(False)
             raw = total_scores(static, stored_nxt, tf)
         with jax.named_scope("replay.victim"):
+            # victim: lexicographic argmin of (score, last_touch, index)
+            # among cached objects. The mask is `cached` itself, which
+            # equals cached\{i}: a hit evicts nothing, so its victim goes
+            # unused, and a miss's i is not cached.
             if use_pallas:
-                victim, victim_score = ops.evict_argmin(raw, touch, mask,
+                victim, victim_score = ops.evict_argmin(raw, touch, cached,
                                                         use_pallas=True)
             else:
-                scores = jnp.where(mask, raw, _BIG)
-                min_s = jnp.min(scores)
-                tie = scores <= min_s  # exact equality; _BIG rows excluded
-                victim = jnp.argmin(jnp.where(tie, touch, INT_BIG))
-                victim_score = scores[victim]
+                scores = jnp.where(cached != 0, raw, _BIG)
+                victim_score = jnp.min(scores)
+                tie = scores <= victim_score  # exact; _BIG rows excluded
+                t_tie = jnp.where(tie, touch, INT_BIG)
+                index = (jax.lax.broadcasted_iota(jnp.int32, tiles, 0) * LANES
+                         + jax.lax.broadcasted_iota(jnp.int32, tiles, 1))
+                victim = jnp.min(jnp.where(tie & (t_tie == jnp.min(t_tie)),
+                                           index, INT_BIG))
         with jax.named_scope("replay.update"):
             full = used >= capacity
             # eq.-(2) semantics: a miss always inserts (mandatory
             # displacement)
             do_insert = ~is_hit
             do_evict = do_insert & full & (victim_score < _BIG)
-            cached = cached.at[victim].set(
-                jnp.where(do_evict, False, cached[victim]))
+            out = (victim // LANES, victim % LANES)
+            # an evicted victim is cached: clearing it is taking its 1 away
+            cached = cached.at[out].add(-do_evict.astype(jnp.int32))
             # GreedyDual aging: L := priority of the evicted victim
             gd_active = (weights[2] + weights[3]) > 0
             infl = jnp.where(do_evict & gd_active, victim_score, infl)
-            my_static = _static_score(weights, tf, freq[i].astype(jnp.float32),
+            my_static = _static_score(weights, tf, freq[at].astype(jnp.float32),
                                       infl, c_over_s[i])
             used = used - jnp.where(do_evict, 1, 0) + jnp.where(do_insert, 1, 0)
-            cached = cached.at[i].set(cached[i] | do_insert)
+            # a hit leaves i cached and a miss inserts it (never as the
+            # victim, which is cached on a miss)
+            cached = cached.at[at].set(1)
             # touches (hit or insert) refresh score, next-use and touch time
-            static = static.at[i].set(my_static)
-            stored_nxt = stored_nxt.at[i].set(nu)
-            touch = touch.at[i].set(t)
+            static = static.at[at].set(my_static)
+            stored_nxt = stored_nxt.at[at].set(nu)
+            touch = touch.at[at].set(t)
         new_state = (cached, static, stored_nxt, touch, freq, used, infl,
                      dollars, hits)
         return new_state, ((dollars, hits) if trace_steps else None)
 
-    init = (jnp.zeros(n, bool), jnp.full(n, _BIG, jnp.float32),
-            jnp.full(n, T, jnp.int32), jnp.zeros(n, jnp.int32),
-            jnp.zeros(n, jnp.int32), jnp.int32(0), jnp.float32(0.0),
+    init = (jnp.zeros(tiles, jnp.int32), jnp.full(tiles, _BIG, jnp.float32),
+            jnp.full(tiles, T, jnp.int32), jnp.zeros(tiles, jnp.int32),
+            jnp.zeros(tiles, jnp.int32), jnp.int32(0), jnp.float32(0.0),
             jnp.float32(0.0), jnp.int32(0))
     ts = jnp.arange(T, dtype=jnp.int32)
     final, traj = jax.lax.scan(step, init, (ts, ids, nxt))

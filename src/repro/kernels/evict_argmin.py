@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .layout import LANES, to_tiles
+from .layout import LANES, rows_per_step
 
 __all__ = ["evict_argmin_pallas"]
 
@@ -62,15 +62,23 @@ def _kernel(scores_ref, touch_ref, mask_ref, idx_out, val_out, touch_best,
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def evict_argmin_pallas(scores: jax.Array, touch: jax.Array, mask: jax.Array,
                         block_n: int = 32768, interpret: bool = False):
-    """Lexicographic argmin of (score, touch, index) over mask==True entries.
+    """Lexicographic argmin of (score, touch, index) over mask != 0 entries.
 
-    scores: (N,) float; touch: (N,) int32; mask: (N,) bool.
-    Returns (victim_index int32 scalar, victim_score float32 scalar);
-    score is +BIG when the mask is empty.
+    scores: (rows, 128) float; touch: (rows, 128) int32; mask: (rows, 128)
+    int32 0/1 flags. Entry i of the table sits at (i // 128, i % 128), and
+    `rows` is a multiple of `rows_per_step(rows, block_n)`, as
+    `layout.to_tiles` lays a flat table out. Returns (victim_index, the
+    entry's flat index, int32 scalar; victim_score float32 scalar); score
+    is +BIG when the mask is empty.
     """
-    s, block_rows = to_tiles(scores.astype(jnp.float32), block_n, 0.0)
-    t, _ = to_tiles(touch.astype(jnp.int32), block_n, _INT_BIG)
-    k, _ = to_tiles(mask.astype(jnp.int32), block_n, 0)
+    rows = scores.shape[0]
+    block_rows = rows_per_step(rows, block_n)
+    if scores.shape[1:] != (LANES,) or rows % block_rows:
+        raise ValueError(f"expected (rows, {LANES}) tiles with rows a "
+                         f"multiple of {block_rows}, got {scores.shape}")
+    s = scores.astype(jnp.float32)
+    t = touch.astype(jnp.int32)
+    k = mask.astype(jnp.int32)
     block = pl.BlockSpec((block_rows, LANES), lambda g: (g, 0))
     carry = pl.BlockSpec((1, LANES), lambda g: (0, 0))
     idx, val = pl.pallas_call(

@@ -9,14 +9,21 @@ in ref.py define the semantics either way.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 from . import ref
 from .evict_argmin import evict_argmin_pallas
+from .layout import to_tiles
 from .interval_occupancy import (interval_occupancy_pallas,
                                  occupancy_feasible_pallas)
 
-__all__ = ["evict_argmin", "interval_occupancy", "occupancy_feasible",
-           "on_tpu"]
+__all__ = ["EVICT_BLOCK_N", "evict_argmin", "interval_occupancy",
+           "occupancy_feasible", "on_tpu"]
+
+# victim selection's default block: 256 rows of 128 was the fastest of
+# 2048..131072 elements for the 96-cell grid's batched call over 2^20
+# objects on a TPU v5e (PERF.md); smaller tables run as one block
+EVICT_BLOCK_N = 32768
 
 
 def on_tpu() -> bool:
@@ -24,19 +31,25 @@ def on_tpu() -> bool:
 
 
 def evict_argmin(scores: jax.Array, touch: jax.Array, mask: jax.Array, *,
-                 block_n: int = 32768, use_pallas: bool | None = None):
+                 block_n: int = EVICT_BLOCK_N, use_pallas: bool | None = None):
     """Victim selection: lexicographic argmin of (score, touch) where mask.
 
-    The default block (256 rows of 128) was the fastest of 2048..131072
-    elements for the 96-cell grid's batched call over 2^20 objects on a
-    TPU v5e (PERF.md); smaller tables run as one block.
+    Takes flat (N,) tables, which the kernel path lays out as (rows, 128)
+    tiles first, or tables already so tiled (`layout.to_tiles` with the same
+    `block_n`), which it reads as they are. Returns the flat victim index.
     """
     if use_pallas is None:
         use_pallas = True
-    if use_pallas:
-        return evict_argmin_pallas(scores, touch, mask, block_n=block_n,
-                                   interpret=not on_tpu())
-    return ref.evict_argmin_ref(scores, touch, mask)
+    if not use_pallas:
+        return ref.evict_argmin_ref(scores.ravel(), touch.ravel(),
+                                    mask.ravel())
+    if scores.ndim == 1:
+        scores, _ = to_tiles(scores, block_n, 0.0)
+        touch, _ = to_tiles(touch.astype(jnp.int32), block_n,
+                            jnp.iinfo(jnp.int32).max)
+        mask, _ = to_tiles(mask.astype(jnp.int32), block_n, 0)
+    return evict_argmin_pallas(scores, touch, mask, block_n=block_n,
+                               interpret=not on_tpu())
 
 
 def interval_occupancy(deltas: jax.Array, *, block_t: int = 2048,

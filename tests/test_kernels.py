@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.evict_argmin import evict_argmin_pallas
 
 
 @pytest.mark.parametrize("N,block_n,dtype", [
@@ -44,6 +45,48 @@ def test_evict_argmin_empty_mask():
     mask = jnp.zeros(128, bool)
     _, gv = ops.evict_argmin(scores, touch, mask, block_n=64)
     assert float(gv) > 1e37  # +BIG sentinel
+
+
+@pytest.mark.parametrize("case", ["random", "touch_ties", "index_ties",
+                                  "empty"])
+def test_evict_argmin_tiled_entry(case):
+    """The kernel on (rows, 128) tiles, read as they are (three grid steps
+    of 8 rows), against the oracle on the flattened tables: ties in score
+    go to the oldest touch, then to the lowest index."""
+    rng = np.random.default_rng(len(case))
+    rows = 24
+    scores = rng.integers(0, 4, (rows, 128)).astype(np.float32)
+    touch = rng.integers(0, 50, (rows, 128)).astype(np.int32)
+    mask = (rng.random((rows, 128)) < 0.3).astype(np.int32)
+    if case == "touch_ties":
+        scores[:] = 1.0
+    elif case == "index_ties":
+        scores[:] = 1.0
+        touch[:] = 7
+    elif case == "empty":
+        mask[:] = 0
+    gi, gv = evict_argmin_pallas(jnp.asarray(scores), jnp.asarray(touch),
+                                 jnp.asarray(mask), block_n=1024,
+                                 interpret=True)
+    wi, wv = ref.evict_argmin_ref(jnp.asarray(scores.ravel()),
+                                  jnp.asarray(touch.ravel()),
+                                  jnp.asarray(mask.ravel() != 0))
+    assert (int(gi), float(gv)) == (int(wi), float(wv))
+    flat = mask.ravel() != 0
+    if case == "empty":
+        assert float(gv) > 1e37  # +BIG sentinel
+    if case == "index_ties":
+        assert int(gi) == int(np.flatnonzero(flat)[0])
+    if case == "touch_ties":
+        assert touch.ravel()[int(gi)] == touch.ravel()[flat].min()
+
+
+def test_evict_argmin_tiled_entry_refuses_partial_blocks():
+    tiles = jnp.zeros((12, 128), jnp.float32)
+    with pytest.raises(ValueError):
+        evict_argmin_pallas(tiles, tiles.astype(jnp.int32),
+                            tiles.astype(jnp.int32), block_n=1024,
+                            interpret=True)
 
 
 @pytest.mark.parametrize("T,block_t,dtype", [

@@ -18,7 +18,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import policies_jax
 from repro.kernels import ops
-from repro.kernels.evict_argmin import evict_argmin_pallas
 from repro.kernels.interval_occupancy import (interval_occupancy_pallas,
                                               occupancy_feasible_pallas)
 
@@ -45,10 +44,12 @@ def _assert_kernel(lowered):
 
 
 @pytest.mark.parametrize("n", [65_536, 1_048_576])
-def test_evict_argmin_compiles(chip, n):
-    _assert_kernel(evict_argmin_pallas.lower(
+def test_evict_argmin_compiles(chip, n, monkeypatch):
+    """Flat tables, laid out as tiles by `ops.evict_argmin` on the way in."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    _assert_kernel(jax.jit(ops.evict_argmin).lower(
         _spec(chip, (n,), jnp.float32), _spec(chip, (n,), jnp.int32),
-        _spec(chip, (n,), jnp.bool_), interpret=False))
+        _spec(chip, (n,), jnp.bool_)))
 
 
 def test_occupancy_feasible_compiles(chip):
@@ -88,27 +89,50 @@ def test_replay_grid_compiles_with_kernel(grid):
 
 
 def test_replay_grid_kernel_and_relayouts_map_to_victim_scope(grid):
-    """The Mosaic call, the operands it is handed and the relayouts that
-    produce them are device time of the step's victim selection."""
-    from repro.launch.hlo_analysis import (_INSTRUCTION, _operands,
-                                           scope_map)
-    text = grid[2]
+    """The Mosaic call is device time of the step's victim selection, and
+    it reads the scan's state as it is: no reshape, convert, copy or pad
+    produces any of its operands, and none makes a whole table of the 96
+    cells anywhere in the loop."""
+    import re
+
+    from repro.launch.hlo_analysis import (_INSTRUCTION, _calls, _operands,
+                                           _split_computations, scope_map)
+    _, lowered, text = grid
     scopes = scope_map(text, policies_jax.STEP_SCOPES)
-    victim = set(scopes["replay.victim"])
-    lines = {}
-    for ls in map(str.strip, text.splitlines()):
-        m = _INSTRUCTION.match(ls)
-        if m:
-            lines[m.group(1)] = ls[m.end():]
+    comps = _split_computations(text)
+    lines, loop = {}, set()
+    for comp, body in comps.items():
+        for ls in body:
+            m = _INSTRUCTION.match(ls)
+            if m:
+                lines[m.group(1)] = ls[m.end():]
+        for callee, kind in _calls(body):
+            if kind == "body":
+                loop |= {_INSTRUCTION.match(ls).group(1) for ls in
+                         comps[callee] if _INSTRUCTION.match(ls)}
+
+    def opcode(name):
+        m = re.match(r"\S+ ([\w\-]+)\(", lines[name])
+        return m.group(1) if m else ""
+
+    def producer(name):
+        while opcode(name) == "bitcast":
+            name = _operands(lines[name])[0]
+        return name
+
+    relayouts = ("reshape", "convert", "copy", "pad")
     kernels = [k for k, rest in lines.items()
                if 'custom_call_target="tpu_custom_call"' in rest]
-    assert kernels and set(kernels) <= victim
-    feed = {o for k in kernels for o in _operands(lines[k])}
-    feed |= {o for f in feed for o in _operands(lines[f])
-             if lines.get(o, "").split(" ", 1)[-1].startswith(
-                 ("reshape(", "convert(", "copy(", "bitcast("))}
-    assert any(lines[f].split(" ", 1)[-1].startswith("reshape(")
-               for f in feed)
-    assert feed <= victim
+    assert kernels and set(kernels) <= set(scopes["replay.victim"])
+    for k in kernels:
+        for o in _operands(lines[k]):
+            assert opcode(producer(o)) not in relayouts, (o, lines[o][:200])
+    # cells x objects: one per-object table of the whole grid
+    table = np.prod(lowered.out_info.shape) * lowered.args_info[0][4].shape[0]
+    for name in loop:
+        dims = re.match(r"\w+\[([\d,]*)\]", lines[name])
+        if dims and opcode(name) in relayouts:
+            size = np.prod([int(d) for d in dims.group(1).split(",") if d])
+            assert size < table, (name, lines[name][:200])
     for scope in policies_jax.STEP_SCOPES:
         assert scopes[scope]
