@@ -4,8 +4,10 @@ import numpy as np
 import pytest
 
 from repro.core import Trace, simulate
-from repro.core.policies_jax import (POLICY_WEIGHTS, _simulate, simulate_jax,
-                                     stack_policy_weights, sweep_jax)
+from repro.core.policies_jax import (POLICY_WEIGHTS, _simulate, cost_density,
+                                     simulate_jax, stack_policy_weights,
+                                     sweep_jax)
+import repro.core.policies_jax as pj
 from repro.core.trace import next_use_indices
 
 
@@ -85,25 +87,47 @@ def test_multi_policy_sweep_accepts_weight_stack():
                   np.array([3]), num_objects=10)
 
 
+def _byte_sizes(rng, N, B):
+    """Power-of-two sizes under a budget of B bytes, with object 0 as
+    large as the cache (it evicts everything) and object 1 larger (it is
+    fetched through)."""
+    sizes = 2 ** rng.integers(0, 4, N)
+    sizes[:2] = B, B + 1
+    return sizes
+
+
+@pytest.mark.parametrize("unit", ["pages", "bytes"])
 @pytest.mark.parametrize("policy", ["lru", "gdsf", "cost_belady"])
-def test_pallas_victim_path_matches_jnp_step_for_step(policy):
+def test_pallas_victim_path_matches_jnp_step_for_step(policy, unit):
     """`_simulate` with the Pallas evict_argmin kernel (interpret mode on
     CPU) must track the jnp victim path through the WHOLE trajectory, not
-    just the final totals."""
+    just the final totals. Under a byte budget the trajectory holds a step
+    that evicts many victims and a fetch-through, and the counts of both
+    follow it step for step too."""
     rng = np.random.default_rng(hash(policy) % 2**32)
-    T, N, B = 150, 16, 5
+    T, N, B = 150, 16, 5 if unit == "pages" else 24
     ids, costs = _rand(rng, T, N)
     nxt = next_use_indices(ids).astype(np.int32)
+    sizes = np.ones(N) if unit == "pages" else _byte_sizes(rng, N, B)
     args = (jnp.asarray(ids), jnp.asarray(nxt),
-            jnp.asarray(costs, jnp.float32), jnp.ones(N, jnp.float32),
+            jnp.asarray(costs, jnp.float32), jnp.asarray(sizes, jnp.float32),
+            jnp.asarray(sizes, jnp.int32),
+            jnp.asarray(cost_density(costs, sizes)),
             jnp.int32(B), jnp.asarray(POLICY_WEIGHTS[policy].as_array()), N)
-    d_j, h_j, (dol_j, hit_j) = _simulate(*args, use_pallas=False,
-                                         trace_steps=True)
-    d_p, h_p, (dol_p, hit_p) = _simulate(*args, use_pallas=True,
-                                         trace_steps=True)
-    np.testing.assert_array_equal(np.asarray(hit_j), np.asarray(hit_p))
-    np.testing.assert_array_equal(np.asarray(dol_j), np.asarray(dol_p))
-    assert float(d_j) == float(d_p) and int(h_j) == int(h_p)
+    *end_j, traj_j = _simulate(*args, use_pallas=False, trace_steps=True)
+    *end_p, traj_p = _simulate(*args, use_pallas=True, trace_steps=True)
+    for a, b in zip(traj_j, traj_p):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert [float(x) for x in end_j] == [float(x) for x in end_p]
+    if unit == "bytes":
+        evictions, bypasses = (np.diff(np.asarray(x), prepend=0)
+                               for x in traj_j[2:])
+        assert evictions.max() >= 4 and bypasses.max() == 1
+        ref = simulate(policy, Trace(ids=ids, sizes=sizes.astype(float)),
+                       costs, float(B))
+        assert int(end_j[2]) == ref.evictions
+        assert int(end_j[3]) == int(np.sum(ids == 1))
+        assert float(end_j[0]) == pytest.approx(ref.dollars, rel=1e-6)
 
 
 def test_pallas_victim_path_full_api():
@@ -123,22 +147,24 @@ def test_pallas_victim_path_full_api():
     np.testing.assert_array_equal(out_j, out_p)
 
 
-def test_step_scopes_cover_the_compiled_loop_cpu():
+@pytest.mark.parametrize("unit", ["pages", "bytes"])
+def test_step_scopes_cover_the_compiled_loop_cpu(unit, monkeypatch):
     """On the jnp path, every instruction of the compiled grid's entry and
-    loop computations lands in one of the step's three scopes or in
-    `unscoped` by the stated rule, and each scope holds device work."""
+    loop computations (the eviction loop's too) lands in one of the step's
+    scopes or in `unscoped` by the stated rule, and each scope holds
+    device work, the victim selection's reductions in the loop's."""
     import re
 
-    import repro.core.policies_jax as pj
     from repro.launch.hlo_analysis import (_INSTRUCTION, _OP_NAME, _calls,
                                            _split_computations)
     from repro.obs import Tracer
+    monkeypatch.setattr(pj, "_EXECUTABLES", {})
     rng = np.random.default_rng(11)
     ids, costs = _rand(rng, 90, 30)
     tracer = Tracer()
     sweep_jax(list(POLICY_WEIGHTS), ids, np.stack([costs, 3 * costs]),
               np.array([3, 9]), num_objects=30, use_pallas=False,
-              tracer=tracer)
+              tracer=tracer, sizes=np.arange(30) % 4 + 1, budget_unit=unit)
     scopes = tracer.spans(name="replay.compile")[0].attrs["scopes"]
     assert pj.step_scopes() == scopes
     assert set(scopes) == {*pj.STEP_SCOPES, "unscoped"}
@@ -147,9 +173,13 @@ def test_step_scopes_cover_the_compiled_loop_cpu():
     text = next(reversed(pj._EXECUTABLES.values())).as_text()
     comps = _split_computations(text)
     entry = re.search(r"ENTRY\s+%?([\w.\-]+)", text).group(1)
-    loops = [c for c, kind in _calls(comps[entry])
-             if kind in ("body", "condition")]
-    assert loops
+    loops, todo = [], [entry]
+    while todo:
+        found = [c for c, kind in _calls(comps[todo.pop()])
+                 if kind in ("body", "condition")]
+        loops += found
+        todo += found
+    assert len(loops) == 4
     for comp in [entry, *loops]:
         for ls in comps[comp]:
             m = _INSTRUCTION.match(ls)
@@ -164,7 +194,7 @@ def test_step_scopes_cover_the_compiled_loop_cpu():
                 assert where[m.group(1)] == "unscoped", ls
             else:
                 assert m.group(1) in where, ls
-    assert any("reduce" in n for n in scopes["replay.victim"])
+    assert any("reduce" in n for n in scopes["replay.evict_bytes"])
     assert all(scopes[s] for s in pj.STEP_SCOPES)
 
 
@@ -172,7 +202,6 @@ def test_no_tracer_builds_no_scope_map_and_opens_no_span(monkeypatch):
     """No tracer, a NullTracer or a disabled Tracer: the first call of a
     shape compiles without building the scope map, and nothing is
     recorded."""
-    import repro.core.policies_jax as pj
     from repro.obs import NullTracer, Tracer
 
     def refuse(hlo):
@@ -191,7 +220,6 @@ def test_no_tracer_builds_no_scope_map_and_opens_no_span(monkeypatch):
 
 
 def test_compiled_grids_are_bounded_and_reused(monkeypatch):
-    import repro.core.policies_jax as pj
     monkeypatch.setattr(pj, "_EXECUTABLES", {})
     monkeypatch.setattr(pj, "_MAX_EXECUTABLES", 2)
     rng = np.random.default_rng(13)
@@ -222,11 +250,13 @@ def test_padded_tiles_kernel_jnp_and_python_agree(N, policy):
     for B in (1, distinct // 4, distinct + 1):
         args = (jnp.asarray(ids), jnp.asarray(nxt),
                 jnp.asarray(costs, jnp.float32), jnp.ones(N, jnp.float32),
+                jnp.ones(N, jnp.int32),
+                jnp.asarray(cost_density(costs, np.ones(N))),
                 jnp.int32(B), w, N)
-        d_j, h_j, (dol_j, hit_j) = _simulate(*args, use_pallas=False,
-                                             trace_steps=True)
-        d_p, h_p, (dol_p, hit_p) = _simulate(*args, use_pallas=True,
-                                             trace_steps=True)
+        d_j, h_j, *_, (dol_j, hit_j, *_) = _simulate(
+            *args, use_pallas=False, trace_steps=True)
+        d_p, h_p, *_, (dol_p, hit_p, *_) = _simulate(
+            *args, use_pallas=True, trace_steps=True)
         np.testing.assert_array_equal(np.asarray(hit_j), np.asarray(hit_p))
         np.testing.assert_array_equal(np.asarray(dol_j), np.asarray(dol_p))
         ref = simulate(policy, tr, costs, float(B))
@@ -235,3 +265,133 @@ def test_padded_tiles_kernel_jnp_and_python_agree(N, policy):
             f"{policy} B={B}"
         if B > distinct:
             assert ref.hits == T - distinct  # never full: cold misses only
+
+
+_PRICES = [(4e-7, 9e-11), (4e-7, 2e-11), (4e-8, 1.2e-10), (4e-8, 8.7e-11)]
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_byte_budget_grid_matches_python(use_pallas):
+    """The 96-cell grid under byte budgets, at the CDN law's shapes
+    (`wiki_cdn_like`: Pareto sizes to tens of MB, a Zipf core of small
+    objects, one-hit wonders), on the jnp path and on the kernel in
+    interpret mode (two policies, for time), equals `policies.py` cell by
+    cell: dollars, evictions and fetch-throughs; the trace span carries
+    their sums."""
+    from repro.core.trace import wiki_cdn_like
+    from repro.obs import Tracer
+    tr = wiki_cdn_like(2048, 512, seed=5)
+    sizes = np.round(tr.sizes)
+    cm = np.stack([f + sizes * e for f, e in _PRICES])
+    budgets = np.round(sizes.sum() * np.array([1 / 128, 1 / 64, 1 / 32,
+                                               1 / 16]))
+    assert sizes.max() > budgets[0]
+    policies = ["gdsf", "cost_belady"] if use_pallas else list(POLICY_WEIGHTS)
+    tracer = Tracer()
+    out = sweep_jax(policies, tr.ids, cm, budgets, num_objects=2048,
+                    sizes=sizes, use_pallas=use_pallas, tracer=tracer,
+                    budget_unit="bytes")
+    args = (jnp.asarray(stack_policy_weights(policies)), jnp.asarray(tr.ids),
+            jnp.asarray(next_use_indices(tr.ids).astype(np.int32)),
+            jnp.asarray(cm, jnp.float32), jnp.asarray(cost_density(cm, sizes)),
+            jnp.asarray(sizes, jnp.float32), jnp.asarray(sizes, jnp.int32),
+            jnp.asarray(budgets, jnp.int32))
+    d, evictions, bypasses, trips = (np.asarray(x) for x in pj._sweep_grid(
+        *args, 2048, use_pallas))
+    np.testing.assert_array_equal(d, out)
+    for q, pol in enumerate(policies):
+        for p in range(len(cm)):
+            for k, B in enumerate(budgets):
+                ref = simulate(pol, Trace(ids=tr.ids, sizes=sizes), cm[p],
+                               float(B))
+                assert out[q, p, k] == pytest.approx(ref.dollars, rel=1e-6)
+                assert evictions[q, p, k] == ref.evictions
+                assert bypasses[q, p, k] == np.sum(sizes[tr.ids] > B)
+    attrs = tracer.spans(name="replay.execute")[0].attrs
+    assert attrs == {"evict_trips": int(trips),
+                     "evictions": int(evictions.sum()),
+                     "bypasses": int(bypasses.sum())}
+    # the loop runs as often as the cell that evicts most at each step
+    assert evictions.max() <= trips <= evictions.sum()
+    assert bypasses.sum() > 0
+
+
+def test_page_budget_is_a_byte_budget_of_one_size():
+    """A budget of pages replays as a byte budget in which every object
+    takes the same bytes: the same dollars, evictions and loop trips, bit
+    for bit, and a budget of 0 pages fetches every request through."""
+    from repro.obs import Tracer
+    rng = np.random.default_rng(14)
+    ids, costs = _rand(rng, 200, 24)
+    cm = np.stack([costs, 2 * costs])
+    sizes = np.full(24, 1000)
+    attrs = {}
+    for unit, scale in (("pages", 1), ("bytes", 1000)):
+        tracer = Tracer()
+        out = sweep_jax(list(POLICY_WEIGHTS), ids, cm,
+                        np.array([0, 3, 7]) * scale, num_objects=24,
+                        sizes=sizes, use_pallas=False, tracer=tracer,
+                        budget_unit=unit)
+        attrs[unit] = tracer.spans(name="replay.execute")[0].attrs
+        if unit == "pages":
+            pages = out
+    np.testing.assert_array_equal(pages, out)
+    assert attrs["pages"] == attrs["bytes"]
+    assert attrs["pages"]["bypasses"] == len(POLICY_WEIGHTS) * 2 * len(ids)
+    np.testing.assert_allclose(
+        pages[:, :, 0], np.broadcast_to(cm[:, ids].sum(1), pages.shape[:2]),
+        rtol=1e-6)
+
+
+def test_byte_budget_needs_whole_byte_sizes():
+    ids = np.arange(8, dtype=np.int32)
+    costs = np.ones((1, 8))
+    for sizes, budgets in ((None, [4]), (np.full(8, 1.5), [4]),
+                           (np.ones(8), [2.0 ** 31]), (-np.ones(8), [4])):
+        with pytest.raises(ValueError):
+            sweep_jax("lru", ids, costs, np.array(budgets), sizes=sizes,
+                      budget_unit="bytes")
+    with pytest.raises(ValueError):
+        sweep_jax("lru", ids, costs, np.array([4]), budget_unit="objects")
+
+
+def test_byte_budget_free_objects_match_python():
+    """Objects that cost nothing to fetch, under a byte budget: a free
+    object's cost-Belady ratio s * gap / c stays finite, so no policy's
+    score turns NaN, and the grid equals `policies.py` cell by cell."""
+    rng = np.random.default_rng(33)
+    T, N = 600, 40
+    ids = rng.integers(0, N, T).astype(np.int32)
+    # up to 64 MB and reused dozens of steps later: s * gap passes the
+    # float32 range when divided by a tiny floor
+    sizes = 2 ** rng.integers(10, 27, N)
+    cm = np.stack([f + sizes * e for f, e in _PRICES[:2]])
+    cm[0, ::3] = 0.0
+    budgets = np.array([sizes.sum() // 8, sizes.sum() // 3])
+    out = sweep_jax(list(POLICY_WEIGHTS), ids, cm, budgets, num_objects=N,
+                    sizes=sizes, use_pallas=False, budget_unit="bytes")
+    assert np.isfinite(out).all()
+    for q, pol in enumerate(POLICY_WEIGHTS):
+        for p in range(len(cm)):
+            for k, B in enumerate(budgets):
+                ref = simulate(pol, Trace(ids=ids, sizes=sizes.astype(float)),
+                               cm[p], float(B))
+                assert out[q, p, k] == pytest.approx(ref.dollars, rel=1e-6), \
+                    (pol, p, k)
+
+
+def test_byte_budget_loop_ends_without_a_victim():
+    """A cell whose victim selection names no victim (here a NaN cost makes
+    a NaN score) stops asking for trips: the loop ends, and the other
+    cells of the grid still replay exactly."""
+    rng = np.random.default_rng(21)
+    ids, costs = _rand(rng, 120, 16)
+    sizes = _byte_sizes(rng, 16, 24)
+    cm = np.stack([costs, costs])
+    cm[0, ids[0]] = np.nan
+    out = sweep_jax(["lru", "gds"], ids, cm, np.array([24]), num_objects=16,
+                    sizes=sizes, use_pallas=False, budget_unit="bytes")
+    for q, pol in enumerate(["lru", "gds"]):
+        ref = simulate(pol, Trace(ids=ids, sizes=sizes.astype(float)),
+                       costs, 24.0)
+        assert out[q, 1, 0] == pytest.approx(ref.dollars, rel=1e-6)

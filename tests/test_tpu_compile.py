@@ -73,26 +73,31 @@ def grid(chip):
         mp.setattr(ops, "on_tpu", lambda: True)
         use_pallas = policies_jax._resolve_use_pallas(None)
         n, t = 65_536, 8_192
-        args = (_spec(chip, (6, 6), jnp.float32),
-                _spec(chip, (t,), jnp.int32), _spec(chip, (t,), jnp.int32),
-                _spec(chip, (4, n), jnp.float32),
-                _spec(chip, (n,), jnp.float32), _spec(chip, (4,), jnp.int32))
-        lowered = policies_jax._sweep_grid.lower(*args, n, use_pallas)
+        lowered = _grid_lowered(chip, use_pallas, n, t)
     return use_pallas, lowered, lowered.compile().as_text()
+
+
+def _grid_lowered(chip, use_pallas, n, t):
+    args = (_spec(chip, (6, 6), jnp.float32),
+            _spec(chip, (t,), jnp.int32), _spec(chip, (t,), jnp.int32),
+            _spec(chip, (4, n), jnp.float32), _spec(chip, (4, n), jnp.float32),
+            _spec(chip, (n,), jnp.float32), _spec(chip, (n,), jnp.int32),
+            _spec(chip, (4,), jnp.int32))
+    return policies_jax._sweep_grid.lower(*args, n, use_pallas)
 
 
 def test_replay_grid_compiles_with_kernel(grid):
     use_pallas, lowered, text = grid
     assert use_pallas
-    assert np.prod(lowered.out_info.shape) == 96
+    assert np.prod(lowered.out_info[0].shape) == 96
     assert "tpu_custom_call" in text
 
 
 def test_replay_grid_kernel_and_relayouts_map_to_victim_scope(grid):
-    """The Mosaic call is device time of the step's victim selection, and
-    it reads the scan's state as it is: no reshape, convert, copy or pad
-    produces any of its operands, and none makes a whole table of the 96
-    cells anywhere in the loop."""
+    """The Mosaic call is device time of the step's victim selection, in
+    the eviction loop's scope, and it reads the scan's state as it is: no
+    reshape, convert, copy or pad produces any of its operands, and none
+    makes a whole table of the 96 cells anywhere in the loops."""
     import re
 
     from repro.launch.hlo_analysis import (_INSTRUCTION, _calls, _operands,
@@ -123,16 +128,98 @@ def test_replay_grid_kernel_and_relayouts_map_to_victim_scope(grid):
     relayouts = ("reshape", "convert", "copy", "pad")
     kernels = [k for k, rest in lines.items()
                if 'custom_call_target="tpu_custom_call"' in rest]
-    assert kernels and set(kernels) <= set(scopes["replay.victim"])
+    assert kernels and set(kernels) <= set(scopes["replay.evict_bytes"])
     for k in kernels:
         for o in _operands(lines[k]):
             assert opcode(producer(o)) not in relayouts, (o, lines[o][:200])
     # cells x objects: one per-object table of the whole grid
-    table = np.prod(lowered.out_info.shape) * lowered.args_info[0][4].shape[0]
+    table = (np.prod(lowered.out_info[0].shape)
+             * lowered.args_info[0][5].shape[0])
     for name in loop:
         dims = re.match(r"\w+\[([\d,]*)\]", lines[name])
         if dims and opcode(name) in relayouts:
             size = np.prod([int(d) for d in dims.group(1).split(",") if d])
             assert size < table, (name, lines[name][:200])
     for scope in policies_jax.STEP_SCOPES:
+        assert scopes[scope], scope
+
+
+@pytest.fixture(scope="module")
+def byte_grid(chip):
+    """The byte-budget grid of the CDN cell (96 cells, 2^15 objects, 20,000
+    steps) with the kernel: (lowered, compiled)."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "on_tpu", lambda: True)
+        lowered = _grid_lowered(chip, True, 32_768, 20_000)
+    return lowered, lowered.compile()
+
+
+def _loops(text):
+    """Instruction lines by name, and the names in each while body and
+    condition reached from the entry (nested ones included)."""
+    import re
+
+    from repro.launch.hlo_analysis import (_INSTRUCTION, _calls,
+                                           _entry_name, _split_computations)
+    comps = _split_computations(text)
+    lines = {}
+    for body in comps.values():
+        for ls in body:
+            m = _INSTRUCTION.match(ls)
+            if m:
+                lines[m.group(1)] = ls[m.end():]
+    loops, todo = {}, [_entry_name(text)]
+    while todo:
+        for callee, kind in _calls(comps[todo.pop()]):
+            if kind in ("body", "condition") and callee not in loops:
+                loops[callee] = [m.group(1) for m in map(_INSTRUCTION.match,
+                                                         comps[callee]) if m]
+                todo.append(callee)
+    whiles = [rest for rest in lines.values()
+              if re.search(r"\) while\(", rest)]
+    return lines, loops, whiles
+
+
+def test_byte_grid_kernel_maps_to_evict_bytes_scope(byte_grid, monkeypatch):
+    """Under a byte budget the Mosaic call runs inside the eviction loop,
+    nested in the scan, and `step_scopes()` puts it in
+    `replay.evict_bytes`."""
+    lowered, compiled = byte_grid
+    text = compiled.as_text()
+    monkeypatch.setattr(policies_jax, "_EXECUTABLES", {"grid": compiled})
+    scopes = policies_jax.step_scopes()
+    lines, loops, whiles = _loops(text)
+    kernels = [k for k, rest in lines.items()
+               if 'custom_call_target="tpu_custom_call"' in rest]
+    assert kernels and set(kernels) <= set(scopes["replay.evict_bytes"])
+    assert len(whiles) == 2 and len(loops) == 4
+    inner = [names for names in loops.values() if set(kernels) & set(names)]
+    assert len(inner) == 1
+    for scope in ("replay.score", "replay.evict_bytes", "replay.update"):
         assert scopes[scope]
+
+
+def test_byte_grid_loop_state_stays_on_chip(byte_grid):
+    """Every per-object table that the scan or the eviction loop carries
+    is in the core's on-chip memory (`S(1)`), and no relayout, copy or
+    select makes a whole table of the 96 cells inside either loop: the
+    tables take single-element writes on each trip."""
+    import re
+
+    lowered, compiled = byte_grid
+    lines, loops, whiles = _loops(compiled.as_text())
+    for rest in whiles:
+        carries = re.findall(r"\w+\[[\d,]*\]\{[^}]*\}",
+                             rest.split(" while(")[0])
+        tables = [c for c in carries if re.match(r"\w+\[[\d,]*,128\]", c)]
+        assert tables
+        for c in tables:
+            assert "S(1)" in c, c
+    table = np.prod(lowered.out_info[0].shape) * 32_768
+    whole = ("reshape", "convert", "copy", "pad", "select", "transpose")
+    for names in loops.values():
+        for name in names:
+            m = re.match(r"\w+\[([\d,]*)\]\S* ([\w\-]+)\(", lines[name])
+            if m and m.group(2) in whole:
+                size = np.prod([int(d) for d in m.group(1).split(",") if d])
+                assert size < table, (name, lines[name][:200])
