@@ -55,12 +55,14 @@ import numpy as np
 
 from .trace import next_use_indices
 from ..kernels import ops
-from ..kernels.layout import LANES, to_tiles
+from ..kernels.layout import LANES, SUBLANES, to_tiles
 
 __all__ = ["PolicyWeights", "POLICY_WEIGHTS", "STEP_SCOPES", "cost_density",
            "simulate_jax", "sweep_jax", "stack_policy_weights", "step_scopes"]
 
 _BIG = jnp.float32(3.4e38)
+# entries of one (8, 128) tile of the scan's state
+_TILE = SUBLANES * LANES
 # the named scopes that partition the scan step: the touch bookkeeping and
 # the score pass, the eviction loop with its victim selections, and the
 # state update
@@ -128,6 +130,20 @@ def _add_compensated(total, carry, x):
     y = x - carry
     t = total + y
     return t, (t - total) - y
+
+
+def _write_tile(table, k, mine, value):
+    """`table` with `value` at the entries of its k-th (8, 128) tile where
+    `mine` holds; the others keep what they held. The tile is sliced from
+    the table's (rows // 8, 8, 128) view, a free reshape of the tiled
+    layout, so the compiler knows the slice is aligned: at a row of the
+    (rows, 128) table it cannot tell that the row is a multiple of 8, and
+    copies each cell's tile as an unaligned slice, several times slower."""
+    tiles = table.reshape(-1, SUBLANES, LANES)
+    start = (k, 0, 0)
+    tile = jax.lax.dynamic_slice(tiles, start, (1, SUBLANES, LANES))
+    return jax.lax.dynamic_update_slice(
+        tiles, jnp.where(mine, value, tile), start).reshape(table.shape)
 
 
 def _evict_until_fits(select, cached, used, infl, miss, size, capacity,
@@ -220,6 +236,8 @@ def _simulate(ids, nxt, costs, sizes, occupancy, c_over_s, capacity, weights,
                              1.0)
     tiles = cost_tiles.shape
     INT_BIG = jnp.int32(2**31 - 1)
+    # each entry's flat offset within an (8, 128) tile
+    offset = jnp.arange(_TILE, dtype=jnp.int32).reshape(SUBLANES, LANES)
     gd_active = (weights[2] + weights[3]) > 0
 
     def total_scores(static, stored_nxt, t):
@@ -285,19 +303,20 @@ def _simulate(ids, nxt, costs, sizes, occupancy, c_over_s, capacity, weights,
             bypass = ~is_hit & ~do_insert
             counts = (counts[0] + evicted, counts[1] + bypass.astype(jnp.int32),
                       counts[2] + trips)
-            # i stays cached or is inserted unless it is fetched through;
-            # the writes of its flag and score then go to a row past the
-            # table, and are dropped. A per-cell row makes them scatters,
-            # as the loop's evictions are: at the shared row, XLA relaid
-            # both whole tables to write them.
-            row = jnp.where(is_hit | do_insert, at[0], tiles[0])
             my_static = _static_score(weights, tf, freq[at].astype(jnp.float32),
                                       infl, c_over_s[i])
             # a hit leaves i cached and a miss inserts it (never as the
             # victim, which is cached on a miss); touches (hit or insert)
-            # refresh score, next-use and touch time
-            cached = cached.at[row, at[1]].set(1)
-            static = static.at[row, at[1]].set(my_static)
+            # refresh score, next-use and touch time. The flag and the
+            # score go in as the aligned (8, 128) tile that holds i, whose
+            # start row every cell shares: one in-place slice update of
+            # each table, where a single element makes a scatter of one
+            # index a cell, or at the shared row a relayout of the whole
+            # table. The tile comes from the loop's output, whose victims
+            # may lie in it; a fetched-through cell keeps its entry.
+            mine = (offset == i % _TILE) & (is_hit | do_insert)
+            cached = _write_tile(cached, i // _TILE, mine, 1)
+            static = _write_tile(static, i // _TILE, mine, my_static)
             stored_nxt = stored_nxt.at[at].set(nu)
             touch = touch.at[at].set(t)
         new_state = (cached, static, stored_nxt, touch, freq, used, infl,

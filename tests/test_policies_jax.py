@@ -395,3 +395,70 @@ def test_byte_budget_loop_ends_without_a_victim():
         ref = simulate(pol, Trace(ids=ids, sizes=sizes.astype(float)),
                        costs, 24.0)
         assert out[q, 1, 0] == pytest.approx(ref.dollars, rel=1e-6)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_byte_budget_victims_in_the_inserted_tile(use_pallas):
+    """Misses whose victims lie in the same (8, 128) tile of the state as
+    the object they insert: the update writes that tile back from the
+    eviction loop's output, so the victims stay evicted. Most requests go
+    to tile 1, a few to tile 0; one object of tile 1 takes the whole
+    budget and evicts everything. The trajectory and the totals equal
+    `policies.py`, on the kernel and on the jnp path."""
+    rng = np.random.default_rng(16)
+    N, T, B = 2048, 300, 24
+    ids = rng.choice(np.r_[np.arange(1024, 1048), np.arange(8)],
+                     T).astype(np.int32)
+    costs = 2.0 ** rng.integers(0, 12, N)
+    sizes = 2 ** rng.integers(0, 4, N)
+    sizes[1030] = B
+    nxt = next_use_indices(ids).astype(np.int32)
+    for policy in ("lru", "gdsf", "cost_belady"):
+        args = (jnp.asarray(ids), jnp.asarray(nxt),
+                jnp.asarray(costs, jnp.float32),
+                jnp.asarray(sizes, jnp.float32), jnp.asarray(sizes, jnp.int32),
+                jnp.asarray(cost_density(costs, sizes)), jnp.int32(B),
+                jnp.asarray(POLICY_WEIGHTS[policy].as_array()), N)
+        d, h, evictions, bypasses, _, (_, _, ev, _) = _simulate(
+            *args, use_pallas=use_pallas, trace_steps=True)
+        evicts = np.diff(np.asarray(ev), prepend=0) > 0
+        assert (evicts & (ids >= 1024)).any() and (evicts & (ids < 8)).any()
+        ref = simulate(policy, Trace(ids=ids, sizes=sizes.astype(float)),
+                       costs, float(B))
+        assert (int(h), int(evictions), int(bypasses)) == \
+            (ref.hits, ref.evictions, 0), policy
+        assert float(d) == ref.dollars, policy
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_fetch_through_in_one_cell_insert_in_another(use_pallas):
+    """At the same step one cell fetches an object through (it is larger
+    than that cell's budget) and another inserts it. The fetched-through
+    cell's tile keeps its entries, neighbours cached in it included: the
+    object misses there every time, and every cell of the grid equals
+    `policies.py` in dollars, evictions and fetch-throughs."""
+    rng = np.random.default_rng(17)
+    N, T = 64, 240
+    ids, costs = _rand(rng, T, N)
+    ids[::6] = 5
+    sizes = 2 ** rng.integers(0, 4, N)
+    sizes[5] = 40
+    budgets = np.array([24, 64])
+    policies = ["lru", "gdsf", "cost_belady"]
+    cm = np.stack([costs, 4 * costs])
+    args = (jnp.asarray(stack_policy_weights(policies)), jnp.asarray(ids),
+            jnp.asarray(next_use_indices(ids).astype(np.int32)),
+            jnp.asarray(cm, jnp.float32), jnp.asarray(cost_density(cm, sizes)),
+            jnp.asarray(sizes, jnp.float32), jnp.asarray(sizes, jnp.int32),
+            jnp.asarray(budgets, jnp.int32))
+    d, evictions, bypasses, _ = (np.asarray(x) for x in pj._sweep_grid(
+        *args, N, use_pallas))
+    np.testing.assert_array_equal(bypasses[:, :, 0], np.sum(ids == 5))
+    np.testing.assert_array_equal(bypasses[:, :, 1], 0)
+    for q, pol in enumerate(policies):
+        for p in range(len(cm)):
+            for k, B in enumerate(budgets):
+                ref = simulate(pol, Trace(ids=ids, sizes=sizes.astype(float)),
+                               cm[p], float(B))
+                assert (d[q, p, k], evictions[q, p, k]) == \
+                    (np.float32(ref.dollars), ref.evictions), (pol, p, B)

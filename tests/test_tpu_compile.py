@@ -223,3 +223,63 @@ def test_byte_grid_loop_state_stays_on_chip(byte_grid):
             if m and m.group(2) in whole:
                 size = np.prod([int(d) for d in m.group(1).split(",") if d])
                 assert size < table, (name, lines[name][:200])
+
+
+@pytest.mark.parametrize("program", ["grid", "byte_grid"])
+def test_update_writes_aligned_tiles_not_scatters(program, request):
+    """The state update writes the inserted object's flag and frozen score
+    into the 96 cells' `cached` and `static` tables as one (8, 128) tile
+    each: an in-place dynamic-update-slice whose every index the compiler
+    knows is aligned, not a scatter of one index a cell. The one scatter
+    that writes a whole table of the grid is the eviction loop's, in
+    `replay.evict_bytes`."""
+    import re
+
+    from repro.launch.hlo_analysis import (_INSTRUCTION, _operands,
+                                           _split_computations, scope_map)
+    fixture = request.getfixturevalue(program)
+    if program == "grid":
+        _, lowered, text = fixture
+    else:
+        lowered, compiled = fixture
+        text = compiled.as_text()
+    comps = _split_computations(text)
+    lines, _, _ = _loops(text)
+    scopes = scope_map(text, policies_jax.STEP_SCOPES)
+    cells = ",".join(map(str, lowered.out_info[0].shape))
+    # a table of the grid as (rows, 128) or as its (rows // 8, 8, 128) view
+    whole = re.compile(rf"\w+\[{cells},(\d+,)?\d+,128\]")
+    tile = re.compile(rf"\w+\[{cells},(1,)?8,128\]")
+    op = re.compile(r"(\w+\[[\d,]*\])\S* ([\w\-]+)\(")
+
+    def runs(name):
+        """(name, result type, opcode, text) of each op that the device op
+        `name` runs: itself, or each op of the fusion it calls."""
+        rest = lines[name]
+        callee = re.search(r"\bcalls=%?([\w.\-]+)", rest)
+        if re.search(r"\bfusion\(", rest) and callee:
+            names = [m.group(1) for m in map(_INSTRUCTION.match,
+                                             comps[callee.group(1)]) if m]
+        else:
+            names = [name]
+        for n in names:
+            m = op.match(lines[n])
+            if m:
+                yield n, m.group(1), m.group(2), lines[n]
+
+    writes = {}
+    for scope, names in scopes.items():
+        for name in names:
+            for n, shape, opcode, rest in runs(name):
+                if whole.fullmatch(shape):
+                    writes.setdefault((scope, opcode), []).append((n, rest))
+    scattered = {scope for scope, opcode in writes if opcode == "scatter"}
+    assert scattered <= {"replay.evict_bytes"}, scattered
+    updates = writes.get(("replay.update", "dynamic-update-slice"), [])
+    assert sorted(rest[:3] for _, rest in updates) == ["f32", "s32"], updates
+    for n, rest in updates:
+        update = _operands(rest)[1]
+        assert tile.fullmatch(op.match(lines[update]).group(1)), (n, update)
+        aligned = re.search(r'"is_index_aligned":\[([a-z,]*)\]', rest)
+        assert aligned and set(aligned.group(1).split(",")) == {"true"}, \
+            (n, rest[:300])
