@@ -16,7 +16,7 @@ zero-egress check; also exports the governed run's metrics registry to
 from __future__ import annotations
 
 from repro.egress.cache import ONLINE_POLICIES
-from repro.online import MetricsRegistry
+from repro.obs import MetricsRegistry
 from repro.online.scenario import (regime_shift_scenario, run_fixed,
                                    run_governed)
 from .common import OUT_DIR, emit, timed

@@ -106,75 +106,6 @@ def serve_trace_overhead(rounds: int = 4, hot_prompts: int = 3,
             Timing(samples["on"]))
 
 
-def shadow_panel_overhead(T: int = 40_000, n_objects: int = 512,
-                          cache_objects: int = 96, repeats: int = 5,
-                          seed: int = 0):
-    """ns/access of the shadow panel's hit fast path vs the generic path.
-
-    `ShadowCache.access` short-circuits LRU/LFU priority recomputes on
-    hits; `_GenericShadow` restores the pre-fast-path body (always route
-    through `_priority` via `_touch`). Both panels replay the identical
-    event stream — counterfactual dollars must agree exactly, and the
-    fast panel must not be slower."""
-    import time as _time
-
-    from repro.online.shadow import ShadowCache, ShadowPanel
-
-    class _GenericShadow(ShadowCache):
-        def access(self, key: str, nbytes: int, miss_cost: float) -> bool:
-            self._clock += 1
-            self._freq[key] = self._freq.get(key, 0) + 1
-            if key in self._sizes:
-                self.hits += 1
-                self._touch(key, nbytes, miss_cost)
-                return True
-            self.misses += 1
-            self.dollars += miss_cost
-            if nbytes <= self.capacity:
-                self._evict_until_fits(nbytes)
-                self._sizes[key] = nbytes
-                self.used += nbytes
-                self._touch(key, nbytes, miss_cost)
-            return False
-
-    rng = np.random.default_rng(seed)
-    nbytes_by_obj = rng.integers(1024, 8192, n_objects)
-    events = [(f"o{z % n_objects}", int(nbytes_by_obj[z % n_objects]))
-              for z in rng.zipf(1.1, T)]
-    cap = float(cache_objects * int(nbytes_by_obj.mean()))
-
-    def make_panels():
-        fast = ShadowPanel(cap)
-        generic = ShadowPanel(cap)
-        generic.shadows = {p: _GenericShadow(p, cap)
-                           for p in generic.policies}
-        return fast, generic
-
-    def replay(panel):
-        shadows = list(panel.shadows.values())
-        for key, nb in events:
-            mc = nb * 1e-9
-            for sh in shadows:
-                sh.access(key, nb, mc)
-
-    # correctness first: identical counterfactual dollars per policy
-    fast, generic = make_panels()
-    replay(fast)
-    replay(generic)
-    assert fast.dollars() == generic.dollars(), (
-        fast.dollars(), generic.dollars())
-
-    # timing: fresh panels per repeat, interleaved to dodge clock drift
-    samples: dict[str, list[float]] = {"fast": [], "generic": []}
-    for _ in range(repeats):
-        fast, generic = make_panels()
-        for name, panel in (("fast", fast), ("generic", generic)):
-            t0 = _time.perf_counter()
-            replay(panel)
-            samples[name].append(_time.perf_counter() - t0)
-    return Timing(samples["fast"]), Timing(samples["generic"]), len(events)
-
-
 def main():
     rng = np.random.default_rng(0)
     T, N, B = 20_000, 500, 64
@@ -245,17 +176,6 @@ def main():
          f"base_ns_per_access={dt_off/T*1e9:.0f};"
          f"traced_add_ns_per_access={(dt_on-dt_off)/T*1e9:.0f};"
          f"null_add_ns_per_access={(dt_null-dt_off)/T*1e9:.0f}")
-
-    # shadow panel hit fast path: same dollars as the generic priority
-    # path (asserted inside), and ns/access must not regress (10% noise
-    # margin on interleaved min-of-repeats)
-    dt_fast, dt_generic, n_ev = shadow_panel_overhead()
-    ok = dt_fast.min <= dt_generic.min * 1.10
-    emit("shadow_panel_ns_access", dt_fast,
-         f"fast_ns={dt_fast.min/n_ev*1e9:.0f};"
-         f"generic_ns={dt_generic.min/n_ev*1e9:.0f};"
-         f"speedup={dt_generic.min/dt_fast.min:.3f}x;ok={ok}")
-    assert ok, (dt_fast.min, dt_generic.min)
     return None
 
 

@@ -1,7 +1,7 @@
 """Policy audit: sweep (policy x price-vector x budget) on the JAX replay
 engine and bracket everything against the exact reference — the paper's
 Table-1 workflow as a one-command operational tool. The whole sweep is
-published through the online metrics registry and exported as JSON
+published through the obs metrics registry and exported as JSON
 (`benchmarks/out/policy_audit_metrics.json`).
 
     PYTHONPATH=src python examples/policy_audit.py
@@ -13,8 +13,8 @@ import numpy as np
 from repro.core import (PRICE_VECTORS, exact_opt_uniform, heterogeneity,
                         miss_costs, twemcache_like)
 from repro.core.policies_jax import sweep_jax
-from repro.online import MetricsRegistry
 from repro.launch.compile_cache import enable_compile_cache
+from repro.obs import MetricsRegistry
 
 OUT = pathlib.Path(__file__).resolve().parent.parent / "benchmarks" / "out"
 

@@ -4,7 +4,9 @@ Sits between compute and the ObjectStore. Pluggable policy (LRU / LFU /
 GDS / GDSF — the online subset of core/policies.py), byte-capacity budget,
 billing-faithful accounting, and an `audit()` that replays the observed
 access trace against the exact offline dollar-optimum (core/opt_exact,
-cost-FOO) — the framework-native use of the paper's reference.
+cost-FOO) — the framework-native use of the paper's reference. Every
+replacement decision, and the resident bytes themselves, belong to a
+`ReplacementMachine` (replacement.py), the same machine each shadow runs.
 
 Governance surface (DESIGN.md §8): every access emits an `AccessEvent` to
 registered listeners (the shadow panel / windowed audit / metrics of
@@ -24,7 +26,6 @@ default to None and cost one branch when absent.
 from __future__ import annotations
 
 import dataclasses
-import heapq
 import itertools
 from typing import Callable, Optional, Protocol
 
@@ -32,12 +33,11 @@ import numpy as np
 
 from repro.core import (Trace, cost_foo, exact_opt_uniform,
                         exact_opt_uniform_sweep, heterogeneity, regret)
+from .replacement import ONLINE_POLICIES, ReplacementMachine
 from .store import ObjectStore
 
 __all__ = ["EgressCache", "AuditReport", "AccessEvent", "AdmissionController",
            "ONLINE_POLICIES"]
-
-ONLINE_POLICIES = ("lru", "lfu", "gds", "gdsf")
 
 _cache_counter = itertools.count()
 
@@ -105,10 +105,9 @@ class EgressCache:
                  policy: str = "gdsf", consumer: Optional[str] = None,
                  admission: Optional[AdmissionController] = None,
                  metrics=None, tracer=None, events=None):
-        assert policy in ONLINE_POLICIES, policy
         self.store = store
-        self.capacity = float(capacity_bytes)
-        self.policy = policy
+        # resident bytes (as payloads) and every replacement decision
+        self._machine = ReplacementMachine(policy, capacity_bytes)
         self.consumer = consumer or f"egress_cache_{next(_cache_counter)}"
         self.meter = store.meter_for(self.consumer)
         self.admission = admission
@@ -127,13 +126,6 @@ class EgressCache:
         # fee-dominated accesses)
         sstar = store.price.crossover_bytes
         self._size_bounds = [sstar * 2.0 ** k for k in range(-8, 9)]
-        self.used = 0.0
-        self._data: dict[str, bytes] = {}
-        self._prio: dict[str, tuple[float, int]] = {}
-        self._heap: list[tuple[float, int, str]] = []
-        self._freq: dict[str, int] = {}
-        self._inflation = 0.0
-        self._clock = 0
         self._listeners: list[Callable[[AccessEvent], None]] = []
         # access log for offline audit
         self._trace_keys: list[str] = []
@@ -141,6 +133,18 @@ class EgressCache:
         self.misses = 0
         self.policy_swaps = 0
         self.bypasses = 0
+
+    @property
+    def policy(self) -> str:
+        return self._machine.policy
+
+    @property
+    def capacity(self) -> float:
+        return self._machine.capacity
+
+    @property
+    def used(self) -> float:
+        return self._machine.used
 
     # ------------------------------------------------------------------
     def add_listener(self, fn: Callable[[AccessEvent], None]) -> None:
@@ -151,37 +155,6 @@ class EgressCache:
         # `set_price` reprices immediately (bit-equal to miss_cost(nbytes))
         return self.store.price.miss_cost_scalar(nbytes)
 
-    def _priority(self, key: str, nbytes: int) -> float:
-        dens = self._miss_cost(nbytes) / max(nbytes, 1)
-        if self.policy == "lru":
-            return float(self._clock)
-        if self.policy == "lfu":
-            return float(self._freq[key])
-        if self.policy == "gds":
-            return self._inflation + dens
-        return self._inflation + self._freq[key] * dens  # gdsf
-
-    def _touch(self, key: str, nbytes: int):
-        pr = self._priority(key, nbytes)
-        self._prio[key] = (pr, self._clock)
-        heapq.heappush(self._heap, (pr, self._clock, key))
-
-    def _evict_until_fits(self, need: float):
-        while self.used + need > self.capacity and self._prio:
-            pr, tt, key = heapq.heappop(self._heap)
-            if self._prio.get(key) != (pr, tt):
-                continue
-            del self._prio[key]
-            data = self._data.pop(key)
-            self.used -= len(data)
-            if self.policy in ("gds", "gdsf"):
-                self._inflation = pr
-            if self.events is not None:
-                # bills nothing now; at stake = the re-fetch cost if touched
-                self.events.record("evict", key, len(data), 0.0,
-                                   self._miss_cost(len(data)), self._clock,
-                                   self.policy)
-
     # ------------------------------------------------------------------
     def set_policy(self, policy: str) -> None:
         """Hot-swap the replacement policy, preserving cache contents.
@@ -189,23 +162,15 @@ class EgressCache:
         Priorities of resident objects are recomputed under the new policy
         and the heap rebuilt; nothing is evicted or refetched, so the swap
         itself bills $0 (asserted in tests/test_serve_billing.py)."""
-        assert policy in ONLINE_POLICIES, policy
         if policy == self.policy:
             return
-        self.policy = policy
-        self._inflation = 0.0
-        self._heap = []
-        for key, data in self._data.items():
-            pr = self._priority(key, len(data))
-            touch = self._prio[key][1]
-            self._prio[key] = (pr, touch)
-            heapq.heappush(self._heap, (pr, touch, key))
+        self._machine.set_policy(policy, self._miss_cost)
         self.policy_swaps += 1
         if self.metrics is not None:
             self.metrics.inc(f"egress.{self.consumer}.policy_swaps")
         if self.events is not None:
-            self.events.record("policy_swap", "", 0, 0.0, 0.0, self._clock,
-                               policy)
+            self.events.record("policy_swap", "", 0, 0.0, 0.0,
+                               self._machine.clock, policy)
 
     # ------------------------------------------------------------------
     def get(self, key: str, event_time: Optional[float] = None) -> bytes:
@@ -223,38 +188,41 @@ class EgressCache:
             t.end(sp)
 
     def _lookup(self, key: str, event_time: Optional[float] = None) -> bytes:
-        self._clock += 1
+        m = self._machine
+        freq = m.request(key)
         self._trace_keys.append(key)
-        self._freq[key] = self._freq.get(key, 0) + 1
-        if key in self._data:
+        if key in m:
             self.hits += 1
-            data = self._data[key]
-            self._touch(key, len(data))
-            self._emit(key, len(data), True, event_time)
+            data = m.payload(key)
+            mc = self._miss_cost(len(data))
+            m.touch(key, mc)
+            self._emit(key, len(data), True, mc, event_time)
             return data
         self.misses += 1
         data = self.store.get(key, consumer=self.consumer)   # billed fetch
         nbytes = len(data)
-        admit = nbytes <= self.capacity
+        mc = self._miss_cost(nbytes)
+        admit = m.fits(nbytes)
         if admit and self.admission is not None:
-            admit = self.admission.admit(key, nbytes, self._freq[key])
+            admit = self.admission.admit(key, nbytes, freq)
             if not admit:
                 self.bypasses += 1
         if admit:
-            self._evict_until_fits(nbytes)
-            self._data[key] = data
-            self.used += nbytes
-            self._touch(key, nbytes)
-        self._emit(key, nbytes, False, event_time)
+            victims = m.insert(key, nbytes, mc, data)
+            if self.events is not None:
+                # bills nothing now; at stake = the re-fetch cost if touched
+                for victim, vbytes, _ in victims:
+                    self.events.record("evict", victim, vbytes, 0.0,
+                                       self._miss_cost(vbytes), m.clock,
+                                       m.policy)
+        self._emit(key, nbytes, False, mc, event_time)
         if self.events is not None:
             self.events.record("admit" if admit else "reject", key, nbytes,
-                               0.0, self._miss_cost(nbytes), self._clock,
-                               self.policy)
+                               0.0, mc, m.clock, m.policy)
         return data
 
-    def _emit(self, key: str, nbytes: int, hit: bool,
+    def _emit(self, key: str, nbytes: int, hit: bool, mc: float,
               event_time: Optional[float] = None) -> None:
-        mc = None
         if self.metrics is not None:
             self.metrics.inc(self._m_hits if hit else self._m_misses)
             if not hit:
@@ -263,19 +231,15 @@ class EgressCache:
                 self._observe_hist(self._m_size_hist, nbytes,
                                    bounds=self._size_bounds)
                 if not hit:
-                    mc = self._miss_cost(nbytes)
                     self._observe_hist(self._m_dollar_hist, mc)
         if self.events is not None or self._listeners:
-            if mc is None:
-                mc = self._miss_cost(nbytes)
+            clock, policy = self._machine.clock, self._machine.policy
             if self.events is not None:
                 self.events.record("hit" if hit else "miss", key, nbytes,
-                                   0.0 if hit else mc, mc, self._clock,
-                                   self.policy)
+                                   0.0 if hit else mc, mc, clock, policy)
             if self._listeners:
-                ev = AccessEvent(key, nbytes, hit, mc, self.policy,
-                                 self._clock,
-                                 float(self._clock) if event_time is None
+                ev = AccessEvent(key, nbytes, hit, mc, policy, clock,
+                                 float(clock) if event_time is None
                                  else float(event_time))
                 for fn in self._listeners:
                     fn(ev)

@@ -2,9 +2,10 @@
 
 `FleetCoordinator` turns gossiped `WindowDelta`s into swap decisions. Each
 host's *vote* for a window is a deterministic function of its own delta —
-`DollarGovernor`'s hysteresis rule verbatim: leave the incumbent only if
-the best policy's windowed shadow dollars undercut the incumbent's by the
-relative `hysteresis` margin. Votes are weighted by the incumbent's
+the swap rule `repro.online.governor.preferred_policy`, which the
+single-host governor applies too: leave the incumbent only if the best
+policy's windowed shadow dollars undercut the incumbent's by the relative
+`hysteresis` margin. Votes are weighted by the incumbent's
 dollars on that host's partition (the dollars actually at stake there), so
 a quiet edge cannot out-vote the host paying the bill. Because the vote is
 derived from the delta itself, no separate ballot messages exist — gossip
@@ -17,8 +18,8 @@ fault-injection tests assert this). The decision rule:
 
   * a policy holding a strict majority of the vote weight wins ("quorum");
   * otherwise, in `mode="central"`, the coordinator breaks the tie from
-    its own merged view — argmin of the fleet-aggregated window dollars,
-    hysteresis against the incumbent ("tiebreak");
+    its own merged view — `preferred_policy` over the fleet-aggregated
+    window dollars ("tiebreak");
   * otherwise the incumbent stands.
 
 Swaps apply atomically across the fleet (`EgressCache.set_policy` on every
@@ -39,6 +40,7 @@ from typing import Callable, Optional
 
 from repro.egress.cache import ONLINE_POLICIES
 from repro.egress.store import ObjectStore
+from repro.online.governor import preferred_policy
 
 from .gossip import GossipState, SimNetwork
 from .node import FleetNode
@@ -88,17 +90,11 @@ class FleetCoordinator:
         return self.state.merge(delta)
 
     def vote_of(self, delta: WindowDelta) -> tuple[str, float]:
-        """One host's (vote, weight) from its own window evidence —
-        DollarGovernor's hysteresis rule, weight = incumbent dollars."""
+        """One host's (vote, weight) from its own window evidence — the
+        swap rule, weight = incumbent dollars."""
         d = delta.dollars
-        inc = self.policy
-        weight = d.get(inc, 0.0)
-        if not d:
-            return inc, 0.0
-        best = min(d, key=d.get)
-        if best != inc and d[best] < (1.0 - self.hysteresis) * weight:
-            return best, weight
-        return inc, weight
+        return (preferred_policy(d, self.policy, self.hysteresis),
+                d.get(self.policy, 0.0))
 
     def poll(self, apply_fn: Optional[Callable[[str, "FleetSwap"], None]]
              = None, network_round: int = 0) -> list[FleetSwap]:
@@ -151,13 +147,11 @@ class FleetCoordinator:
         if tally[winner] > 0.5 * total:
             return winner, "quorum", record
         if self.mode == "central":
-            # centralized tiebreak: fleet-aggregated window dollars, same
-            # hysteresis rule against the incumbent
-            agg = self.state.fleet_window_dollars(wid)
-            inc = self.policy
-            best = min(agg, key=agg.get)
-            if best != inc and agg[best] < (1.0 - self.hysteresis) * \
-                    agg.get(inc, 0.0):
+            # centralized tiebreak: the swap rule on fleet-aggregated
+            # window dollars
+            best = preferred_policy(self.state.fleet_window_dollars(wid),
+                                    self.policy, self.hysteresis)
+            if best != self.policy:
                 return best, "tiebreak", record
         return self.policy, "quorum", record
 

@@ -1,7 +1,6 @@
 """Metrics registry for the observability layer (DESIGN.md §9).
 
-Promoted here from `repro.online.metrics` (which re-exports it for
-back-compat): a single process-local registry of counters, gauges, time
+A single process-local registry of counters, gauges, time
 series and — new in the obs layer — log-bucketed histograms, that
 `ObjectStore`, `EgressCache`, `ServeEngine`, and the dollar-governor all
 publish through. Publishers hold it duck-typed (anything with `.inc` /

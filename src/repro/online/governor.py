@@ -6,10 +6,11 @@ Subscribes to an `EgressCache`'s access stream and drives three organs:
     full online policy set, $0 of extra egress;
   * the windowed exact audit (`window.py`) — a live OPT-dollars bracket
     and regret estimate over recent traffic;
-  * the swap rule — every `window` accesses, compare each policy's
-    *windowed* shadow dollars; if the best shadow undercuts the incumbent
-    policy's shadow by more than `hysteresis` (relative), hot-swap the
-    live cache via `set_policy` (contents preserved, $0 to swap).
+  * the swap rule (`preferred_policy`, shared with the fleet's votes and
+    tiebreak) — every `window` accesses, compare each policy's *windowed*
+    shadow dollars; if the best shadow undercuts the incumbent policy's
+    shadow by more than `hysteresis` (relative), hot-swap the live cache
+    via `set_policy` (contents preserved, $0 to swap).
 
 Comparisons are shadow-vs-shadow (the incumbent's own shadow, not the live
 meter): all shadows start equally cold when the governor attaches and see
@@ -25,7 +26,22 @@ from repro.egress.cache import ONLINE_POLICIES, AccessEvent, EgressCache
 from .shadow import ShadowPanel
 from .window import WindowedAuditor
 
-__all__ = ["DollarGovernor", "SwapEvent"]
+__all__ = ["DollarGovernor", "SwapEvent", "preferred_policy"]
+
+
+def preferred_policy(dollars: dict[str, float], incumbent: str,
+                     hysteresis: float) -> str:
+    """The one swap rule: the cheapest policy (first minimum in iteration
+    order) if it undercuts the incumbent's dollars by more than the
+    relative `hysteresis` margin, else the incumbent. An absent incumbent
+    counts $0, so windowed dollars (never negative) cannot displace it."""
+    if not dollars:
+        return incumbent
+    best = min(dollars, key=dollars.get)
+    if best != incumbent and \
+            dollars[best] < (1.0 - hysteresis) * dollars.get(incumbent, 0.0):
+        return best
+    return incumbent
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,9 +90,8 @@ class DollarGovernor:
                 self.metrics.observe(f"governor.window_dollars.{p}", d,
                                      step=clock)
         incumbent = self.cache.policy
-        best = min(deltas, key=lambda p: deltas[p])
-        if (best != incumbent and incumbent in deltas
-                and deltas[best] < (1.0 - self.hysteresis) * deltas[incumbent]):
+        best = preferred_policy(deltas, incumbent, self.hysteresis)
+        if best != incumbent:
             self.cache.set_policy(best)
             self.swaps.append(SwapEvent(clock, incumbent, best, deltas))
             if self.metrics is not None:

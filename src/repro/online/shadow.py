@@ -1,13 +1,13 @@
 """Shadow-policy panel: counterfactual dollars for every online policy.
 
-Each `ShadowCache` is a metadata-only replica of `EgressCache`'s priority
-machinery (same LRU/LFU/GDS/GDSF formulas as `core/policies.py`, same
-lazy-deletion heap and last-touch tiebreak) that holds sizes instead of
-bytes. The panel subscribes to the live cache's `AccessEvent` stream and
-replays every request against all shadows simultaneously, accruing the
-dollars each policy WOULD have billed — without ever touching the
-`ObjectStore`, so shadowing bills $0 of extra egress (asserted via
-per-consumer meters in tests).
+Each `ShadowCache` is the live cache's own `ReplacementMachine`
+(`repro.egress.replacement`) without bytes, plus the bill it would run up.
+Run under the live cache's policy, a shadow evicts what the live cache
+evicts and bills what it bills. The panel subscribes to the live cache's
+`AccessEvent` stream and replays every request against all shadows
+simultaneously, accruing the dollars each policy WOULD have billed —
+without ever touching the `ObjectStore`, so shadowing bills $0 of extra
+egress (asserted via per-consumer meters in tests).
 
 Miss costs come from the event (`AccessEvent.miss_cost`, priced at access
 time), so a mid-stream price flip (`ObjectStore.set_price`) is reflected
@@ -15,84 +15,32 @@ in every shadow's counterfactual bill exactly as in the live one.
 """
 from __future__ import annotations
 
-import heapq
-
 from repro.egress.cache import ONLINE_POLICIES, AccessEvent
+from repro.egress.replacement import ReplacementMachine
 
 __all__ = ["ShadowCache", "ShadowPanel"]
 
 
-class ShadowCache:
+class ShadowCache(ReplacementMachine):
     """Metadata-only cache simulation: keys, sizes, priorities — no bytes."""
 
     def __init__(self, policy: str, capacity_bytes: float):
-        assert policy in ONLINE_POLICIES, policy
-        self.policy = policy
-        self.capacity = float(capacity_bytes)
-        self.used = 0.0
-        self._sizes: dict[str, int] = {}          # resident keys -> bytes
-        self._prio: dict[str, tuple[float, int]] = {}
-        self._heap: list[tuple[float, int, str]] = []
-        self._freq: dict[str, int] = {}
-        self._inflation = 0.0
-        self._clock = 0
+        super().__init__(policy, capacity_bytes)
         self.hits = 0
         self.misses = 0
         self.dollars = 0.0       # counterfactual: what this policy would bill
 
-    def _priority(self, key: str, nbytes: int, miss_cost: float) -> float:
-        dens = miss_cost / max(nbytes, 1)
-        if self.policy == "lru":
-            return float(self._clock)
-        if self.policy == "lfu":
-            return float(self._freq[key])
-        if self.policy == "gds":
-            return self._inflation + dens
-        return self._inflation + self._freq[key] * dens  # gdsf
-
-    def _touch(self, key: str, nbytes: int, miss_cost: float):
-        pr = self._priority(key, nbytes, miss_cost)
-        self._prio[key] = (pr, self._clock)
-        heapq.heappush(self._heap, (pr, self._clock, key))
-
-    def _evict_until_fits(self, need: float):
-        while self.used + need > self.capacity and self._prio:
-            pr, tt, key = heapq.heappop(self._heap)
-            if self._prio.get(key) != (pr, tt):
-                continue
-            del self._prio[key]
-            self.used -= self._sizes.pop(key)
-            if self.policy in ("gds", "gdsf"):
-                self._inflation = pr
-
     def access(self, key: str, nbytes: int, miss_cost: float) -> bool:
         """Replay one request; returns True on a (counterfactual) hit."""
-        self._clock += 1
-        freq = self._freq.get(key, 0) + 1
-        self._freq[key] = freq
-        if key in self._sizes:
+        self.request(key)
+        if key in self:
             self.hits += 1
-            # hit fast path: LRU/LFU priorities are just the clock / count —
-            # skip the policy dispatch chain and the density division that
-            # `_priority` would redo per hit (bench_policy_throughput
-            # asserts the panel's ns/access against the generic path)
-            policy = self.policy
-            if policy == "lru":
-                pr = float(self._clock)
-            elif policy == "lfu":
-                pr = float(freq)
-            else:
-                pr = self._priority(key, nbytes, miss_cost)
-            self._prio[key] = (pr, self._clock)
-            heapq.heappush(self._heap, (pr, self._clock, key))
+            self.touch(key, miss_cost)
             return True
         self.misses += 1
         self.dollars += miss_cost
-        if nbytes <= self.capacity:
-            self._evict_until_fits(nbytes)
-            self._sizes[key] = nbytes
-            self.used += nbytes
-            self._touch(key, nbytes, miss_cost)
+        if self.fits(nbytes):
+            self.insert(key, nbytes, miss_cost)
         return False
 
     @property

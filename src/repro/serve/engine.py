@@ -25,7 +25,8 @@ from repro.egress.cache import EgressCache
 from repro.egress.store import ObjectStore
 from repro.fleet import Fleet
 from repro.models.registry import ModelApi
-from repro.online import DollarGovernor, MetricsRegistry, WindowedAuditor
+from repro.obs import MetricsRegistry
+from repro.online import DollarGovernor, WindowedAuditor
 
 __all__ = ["ServeEngine", "Request"]
 

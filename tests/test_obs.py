@@ -172,13 +172,6 @@ def test_prometheus_exposition_parses():
     assert "online_window_regret_last 0.25" in lines
 
 
-def test_metrics_registry_backcompat_reexport():
-    from repro.obs.metrics import MetricsRegistry as obs_reg
-    from repro.online import MetricsRegistry as online_pkg_reg
-    from repro.online.metrics import MetricsRegistry as online_mod_reg
-    assert obs_reg is online_pkg_reg is online_mod_reg
-
-
 # ---------------------------------------------------------------------------
 # egress wiring: spans + events + histograms off one live cache
 

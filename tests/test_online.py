@@ -3,10 +3,12 @@ s*-aware admission, governor hot-swap, per-consumer billing attribution."""
 import numpy as np
 import pytest
 
+from repro.core import Trace, simulate
 from repro.core.pricing import PRICE_VECTORS, PriceVector
 from repro.egress import EgressCache, ObjectStore
-from repro.online import (DollarGovernor, MetricsRegistry, SStarAdmission,
-                          ShadowCache, ShadowPanel, WindowedAuditor)
+from repro.obs import EventLog, MetricsRegistry
+from repro.online import (DollarGovernor, SStarAdmission, ShadowCache,
+                          ShadowPanel, WindowedAuditor, preferred_policy)
 from repro.online.scenario import (EGRESS_HEAVY, FEE_HEAVY,
                                    regime_shift_scenario, run_fixed,
                                    run_governed)
@@ -122,21 +124,64 @@ def test_shadow_panel_bills_zero_egress():
     assert all(d > 0 for d in panel.dollars().values())
 
 
-def test_shadow_matches_live_policy_exactly():
-    """A shadow running the live cache's own policy must reproduce its bill
-    step-for-step: same priorities, same tiebreaks, same dollars."""
-    for policy in ONLINE:
-        store = _uniform_store(n=24, size=2048)
-        cache = EgressCache(store, 5 * 2048, policy, consumer=f"live_{policy}")
-        shadow = ShadowCache(policy, cache.capacity)
-        cache.add_listener(
-            lambda ev, sh=shadow: sh.access(ev.key, ev.nbytes, ev.miss_cost))
-        rng = np.random.default_rng(3)
-        for i in rng.zipf(1.3, 600) % 24:
-            cache.get(f"o{i}")
-        assert shadow.hits == cache.hits, policy
-        assert shadow.misses == cache.misses, policy
-        assert shadow.dollars == pytest.approx(cache.meter.dollars), policy
+@pytest.mark.parametrize("law", ["uniform", "variable"])
+@pytest.mark.parametrize("policy", ONLINE)
+def test_live_shadow_and_oracle_agree(policy, law):
+    """One trace through the live cache, a shadow of the same policy on its
+    listener, and the independent oracle `core.policies.simulate`: equal
+    hits, misses and evictions, and the same dollars."""
+    rng = np.random.default_rng(3)
+    n = 24
+    if law == "uniform":
+        sizes, capacity = np.full(n, 2048), 5 * 2048
+    else:   # 512 B to 16 KiB (32x); the 16 KiB objects exceed the budget
+        sizes, capacity = 512 * 2 ** rng.integers(0, 6, n), 12_000
+    store = ObjectStore("s3_internet")
+    for i, s in enumerate(sizes):
+        store.put(f"o{i}", bytes(int(s)))
+    ids = (rng.zipf(1.3, 600) % n).astype(np.int32)
+    events = EventLog(10_000)
+    cache = EgressCache(store, capacity, policy, events=events)
+    shadow = ShadowCache(policy, capacity)
+    victims = []                 # per shadow insert, its victims in order
+    insert = shadow.insert
+
+    def spy(*args):
+        out = insert(*args)
+        victims.append([key for key, _, _ in out])
+        return out
+
+    shadow.insert = spy
+    cache.add_listener(
+        lambda ev: shadow.access(ev.key, ev.nbytes, ev.miss_cost))
+    for i in ids:
+        cache.get(f"o{i}")
+    ref = simulate(policy, Trace(ids=ids, sizes=sizes.astype(np.float64)),
+                   store.price.miss_cost(sizes), float(capacity))
+
+    assert (cache.hits, cache.misses) == (shadow.hits, shadow.misses) \
+        == (ref.hits, ref.misses)
+    live_victims = [ev.key for ev in events.events("evict")]
+    assert live_victims == [key for v in victims for key in v]
+    assert len(live_victims) == ref.evictions > 0
+    assert shadow.dollars == cache.meter.dollars
+    assert cache.meter.dollars == pytest.approx(ref.dollars, rel=1e-12, abs=0)
+    if law == "variable":
+        assert sizes.max() >= 8 * sizes.min()
+        assert any(sizes[i] > capacity for i in ids)       # fetch-through
+        assert max(map(len, victims)) >= 2      # evict-until-fits looped
+
+
+@pytest.mark.parametrize("dollars,incumbent,expected", [
+    ({"lru": 1.0, "gdsf": 0.8}, "lru", "gdsf"),    # undercuts by > 10%
+    ({"lru": 1.0, "gdsf": 0.95}, "lru", "lru"),    # undercuts by < 10%
+    ({"gds": 0.5, "gdsf": 0.2}, "lru", "lru"),     # incumbent absent
+    ({"gdsf": 0.0, "lru": 0.0}, "lru", "lru"),     # incumbent at $0
+    ({"lru": 0.3, "gdsf": 0.5}, "lru", "lru"),     # best is the incumbent
+    ({}, "lfu", "lfu"),                            # no evidence
+])
+def test_preferred_policy(dollars, incumbent, expected):
+    assert preferred_policy(dollars, incumbent, 0.1) == expected
 
 
 # ---------------------------------------------------------------------------
@@ -276,10 +321,10 @@ def test_policy_hot_swap_preserves_contents_and_bill():
     cache = EgressCache(store, 8 * 4096, "lru")
     for i in range(8):
         cache.get(f"o{i}")
-    resident = dict(cache._data)
+    resident = cache._machine.resident()
     bill = cache.meter.dollars
     cache.set_policy("gdsf")
-    assert cache._data == resident
+    assert cache._machine.resident() == resident
     assert cache.used == sum(len(v) for v in resident.values())
     assert cache.meter.dollars == bill          # the swap itself bills $0
     for i in range(8):

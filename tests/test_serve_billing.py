@@ -45,9 +45,9 @@ def test_hot_swap_mid_stream_preserves_contents_and_billing():
         engine.serve([Request(0, p, 2)])
         engine.serve([Request(1, p, 2)])           # warm: 1 GET per prefix
     gets_before = engine.store.meter.gets
-    resident = set(engine.cache._data)
+    resident = set(engine.cache._machine.resident())
     engine.cache.set_policy("lru")                 # hot-swap mid-stream
-    assert set(engine.cache._data) == resident     # contents preserved
+    assert set(engine.cache._machine.resident()) == resident     # contents preserved
     out = [engine.serve([Request(2, p, 2)])[0].output for p in prompts]
     assert engine.store.meter.gets == gets_before  # swap never re-bills
     assert all(o is not None for o in out)
